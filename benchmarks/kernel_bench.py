@@ -96,6 +96,8 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name, us, derived in run():
         print(f"{name},{us:.1f},{derived}")

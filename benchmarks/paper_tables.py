@@ -119,6 +119,8 @@ def run_all(labels=LABELS, tables=("5", "6", "7")):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import sys
     labels = LABELS if len(sys.argv) < 2 else tuple(
         int(x) for x in sys.argv[1].split(","))

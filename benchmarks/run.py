@@ -50,8 +50,10 @@ def main() -> None:
 
     # --- roofline (subprocess: needs 512 forced host devices) --------------
     if not int(os.environ.get("REPRO_BENCH_SKIP_ROOFLINE", "0")):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = str(ROOT / "src")
+        # the child only lowers, on forced CPU host devices: it must stay
+        # off the chip this process already holds
+        env = dict(os.environ, PYTHONPATH=str(ROOT / "src"),
+                   JAX_PLATFORMS="cpu")
         proc = subprocess.run(
             [sys.executable, "-m", "benchmarks.roofline", "--skip-existing"],
             cwd=ROOT, env=env, capture_output=True, text=True)
@@ -64,4 +66,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -58,11 +58,11 @@ import numpy as np
 from repro.core import mesh_federation as MF
 from repro.core import telemetry as TEL
 from repro.core import trust as TR
-from repro.core.federation import (_exchange_round_bytes, _policy_round_body,
-                                   _stack_trees, _tree_bytes, _tree_row,
-                                   _wants_per_round)
-from repro.core.hfl import (FederatedClient, _eval_mse, _train_step,
-                            pool_kernel_available)
+from repro.core.federation import (_exchange_round_bytes,
+                                   _hold_client_copies_on_host,
+                                   _policy_round_body, _stack_trees,
+                                   _tree_bytes, _tree_row, _wants_per_round)
+from repro.core.hfl import FederatedClient, _eval_mse, _train_step
 from repro.core.policies import FederationPolicies
 from repro.optim import adam
 
@@ -541,7 +541,6 @@ def _make_mesh_hetero_epoch_fn(lr: float, plan: CohortPlan, w: int,
     through a tiny (D, max_nf) gather), everything downstream
     replicated-deterministic exactly like
     ``mesh_federation._make_mesh_epoch_fn``."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = MF.client_axis(mesh)
@@ -584,11 +583,11 @@ def _make_mesh_hetero_epoch_fn(lr: float, plan: CohortPlan, w: int,
         # from the replicated pool carry / collectively-reduced scores);
         # a single ``rep`` prefixes the whole tuple, as for trust above
         out_specs = out_specs + (rep,)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         epoch, mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=False)
+        check_vma=False)
     return jax.jit(sharded, donate_argnums=(0, 1, 2, 3, 4, 5, 6))
 
 
@@ -695,10 +694,11 @@ def _fit_cohorted(fed, n_epochs: int, cbs) -> None:
     rounds_t, val_t = tuple(rounds_t), tuple(val_t)
     params_t, opt_t = tuple(params_l), tuple(opt_l)
     best_val_t, best_params_t = tuple(bv_l), tuple(bp_l)
+    del stacked, params_l, opt_l, bv_l, bp_l
 
     pool_heads = stack_hetero_pool(fed.pool, names, plan.nfs, plan.max_nf)
     pool_age = jnp.asarray([fed.pool.age_of(n_) for n_ in names], jnp.int32)
-    use_kernel = cfg.use_pool_kernel and pool_kernel_available()
+    use_kernel = cfg.use_pool_kernel
     lut = hetero_selection_lut(names, plan.nfs, plan.max_nf)
     admission = fed._admission()
     smask = fed._straggler_mask
@@ -760,6 +760,7 @@ def _fit_cohorted(fed, n_epochs: int, cbs) -> None:
             pool_heads=pool_heads, pool_age=pool_age, key=key,
             best_val_t=best_val_t, best_params_t=best_params_t,
             rounds_t=rounds_t, val_t=val_t)
+        _hold_client_copies_on_host(fed)
 
     def make_epoch_fn(do_federate: bool, do_eval: bool,
                       exchange_every: int = 1):

@@ -51,10 +51,9 @@ from repro.core import mesh_federation as MF
 from repro.core import telemetry as TEL
 from repro.core import trust as TR
 from repro.core.hfl import (FederatedClient, HeadPool, HFLConfig,
-                            _eval_mse, _pool_kernel_ops, _train_step,
-                            pool_errors, pool_errors_kernel,
-                            pool_kernel_available)
+                            _eval_mse, _train_step, pool_errors)
 from repro.core.policies import FederationPolicies, policy_from_spec
+from repro.kernels.pool_mlp import ops as pool_ops
 from repro.optim import adam
 
 
@@ -284,7 +283,8 @@ def policy_round(client: FederatedClient, pool: HeadPool,
     chosen, sel_entries = [], []
     for i in range(client.nf):
         if sel.needs_errors:
-            score_fn = pool_errors_kernel if use_kernel else pool_errors
+            score_fn = (pool_ops.pool_mlp_errors if use_kernel
+                        else pool_errors)
             errs = np.asarray(score_fn(stacked, jnp.asarray(xd_R[:, i]),
                                        jnp.asarray(y_R)))
             errs = np.where(valid, errs, np.inf)
@@ -686,11 +686,11 @@ def _policy_round_body(heads, pool_heads, pool_age, xd_R, y_R, active, key,
             sweep equals the corresponding slice of the full sweep."""
             xd_i = jnp.moveaxis(xd_R[i], 1, 0)          # (nf, R, w)
             if use_kernel:
-                ops = _pool_kernel_ops()
                 if valid_rows is not None:
-                    return ops.pool_mlp_errors_shard(pool_rows, xd_i,
-                                                     y_R[i], valid_rows)
-                return ops.pool_mlp_errors_features(pool_rows, xd_i, y_R[i])
+                    return pool_ops.pool_mlp_errors_shard(pool_rows, xd_i,
+                                                          y_R[i], valid_rows)
+                return pool_ops.pool_mlp_errors_features(pool_rows, xd_i,
+                                                         y_R[i])
             return jax.vmap(
                 lambda xf: pool_errors(pool_rows, xf, y_R[i]))(xd_i)
 
@@ -917,6 +917,17 @@ def stack_pool(pool: HeadPool, names: Sequence[str], nf: int):
     return _stack_trees(
         [_stack_trees([pool.entries[(n, f)] for f in range(nf)])
          for n in names])
+
+
+def _hold_client_copies_on_host(fed) -> None:
+    """Move the clients' own params / Adam state / best params and the
+    pool entries to the host.  A mesh fit calls this once its state is
+    partitioned: until its ``sync()`` writes rows back they are stale
+    copies, which would otherwise sit whole on the default device."""
+    for c in fed.clients:
+        c.params, c.opt_state, c.best_params = jax.device_get(
+            (c.params, c.opt_state, c.best_params))
+    fed.pool.entries = jax.device_get(fed.pool.entries)
 
 
 def _tree_row(tree, i):
@@ -1264,6 +1275,7 @@ def _fit_batched(fed: "Federation", n_epochs: int, cbs) -> None:
             t[:, :m].reshape((C, n_sub, R) + t.shape[2:]), 1, 0)
 
     xs_r, xd_r, y_r = rounds_axis(xs), rounds_axis(xd), rounds_axis(y)
+    del xs, xd, y
 
     params = _stack_trees([c.params for c in clients])
     opt_state = _stack_trees([c.opt_state for c in clients])
@@ -1271,7 +1283,7 @@ def _fit_batched(fed: "Federation", n_epochs: int, cbs) -> None:
     # initial publication; a restored fit sees the checkpointed pool)
     pool_heads = stack_pool(fed.pool, names, nf)
     pool_age = jnp.asarray([fed.pool.age_of(n_) for n_ in names], jnp.int32)
-    use_kernel = cfg.use_pool_kernel and pool_kernel_available()
+    use_kernel = cfg.use_pool_kernel
     lut = _selection_lut(names, nf)
     admission = fed._admission()
     smask = fed._straggler_mask
@@ -1331,6 +1343,7 @@ def _fit_batched(fed: "Federation", n_epochs: int, cbs) -> None:
             pool_heads=pool_heads, pool_age=pool_age, key=key,
             best_val=best_val, best_params=best_params,
             rounds_data=(xs_r, xd_r, y_r), val_data=val)
+        _hold_client_copies_on_host(fed)
 
     def make_epoch_fn(do_federate: bool, do_eval: bool,
                       exchange_every: int = 1):

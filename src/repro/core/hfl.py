@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import importlib
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
@@ -50,8 +49,7 @@ class HFLConfig:
     patience: int = 3
     mode: str = "hfl"            # hfl | no | random | always
     use_pool_kernel: bool = False  # Pallas pool-scoring kernel (compiled on
-                                   # TPU, experimentally on GPU; interpret-
-                                   # mode elsewhere)
+                                   # an accelerator, interpret mode on CPU)
     seed: int = 0
 
 
@@ -220,30 +218,6 @@ def pool_errors(pool_stacked, xd_i, y):
     preds = N.head_pool_apply(pool_stacked, xd_i)      # (ns, R)
     errs = jnp.mean((y[None, :] - preds) ** 2, axis=1)
     return jnp.where(jnp.isfinite(errs), errs, jnp.inf)
-
-
-@functools.lru_cache(maxsize=None)
-def _pool_kernel_ops():
-    """Cached resolver for the Pallas pool-scoring module: one import at
-    first dispatch, not one per round (failed imports are NOT cached —
-    lru_cache only memoizes successful returns)."""
-    return importlib.import_module("repro.kernels.pool_mlp.ops")
-
-
-def pool_errors_kernel(pool_stacked, xd_i, y):
-    """Pallas fused pool sweep — compiled on TPU/GPU, interpreted elsewhere
-    (see src/repro/kernels/pool_mlp)."""
-    return _pool_kernel_ops().pool_mlp_errors(pool_stacked, xd_i, y)
-
-
-def pool_kernel_available() -> bool:
-    """ImportError only — a genuinely broken kernel module must surface, not
-    silently fall back to the vmap path."""
-    try:
-        _pool_kernel_ops()
-        return True
-    except ImportError:
-        return False
 
 
 @jax.jit

@@ -3,7 +3,7 @@
 The batched engine (``repro.core.federation._fit_batched``) stacks all C
 clients' state on a leading axis and scans the whole epoch inside one jitted
 dispatch.  This module runs that SAME epoch body under
-:func:`jax.experimental.shard_map.shard_map` on a 1-D
+:func:`jax.shard_map` on a 1-D
 :class:`jax.sharding.Mesh` with a ``clients`` axis, so the population is
 *partitioned* across devices instead of living on one:
 
@@ -67,7 +67,6 @@ from typing import Optional
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -261,9 +260,9 @@ def _make_mesh_epoch_fn(lr: float, nf: int, w: int,
         # back replicated; a single ``rep`` covers the whole tuple (specs
         # are pytree prefixes, as for the trust stats pair above)
         out_specs = out_specs + (rep,)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         epoch, mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=False)
+        check_vma=False)
     return jax.jit(sharded, donate_argnums=(0, 1, 2, 3, 4, 5, 6))

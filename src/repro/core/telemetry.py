@@ -45,6 +45,7 @@ import json
 import time
 from typing import Dict, Iterable, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.faults import QUARANTINE_AGE
@@ -323,23 +324,16 @@ class FlightRecorder:
         if not self.plan.spans:
             yield
             return
-        ann = None
-        if self.plan.profile:
-            try:
-                import jax
-                ann = jax.profiler.TraceAnnotation(name)
-                ann.__enter__()
-            except Exception:
-                ann = None
+        ann = (jax.profiler.TraceAnnotation(name) if self.plan.profile
+               else contextlib.nullcontext())
         t0 = time.perf_counter()
         ts = _now_us(self._origin)
         depth, self._depth = self._depth, self._depth + 1
         try:
-            yield
+            with ann:
+                yield
         finally:
             self._depth -= 1
-            if ann is not None:
-                ann.__exit__(None, None, None)
             dur = int(round((time.perf_counter() - t0) * 1e6))
             self.events.append({"type": "span", "name": name, "ts": ts,
                                 "dur": dur, "depth": depth, **attrs})

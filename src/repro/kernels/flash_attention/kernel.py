@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1.0e38
 
 
@@ -66,7 +68,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bkv: int, seq: int,
 
 def flash_attention_bhsd(q, k, v, *, window: Optional[int] = None,
                          logit_softcap: float = 0.0, bq: int = 256,
-                         bkv: int = 256, interpret: bool = True):
+                         bkv: int = 256, interpret=None):
     """q: (B, H, S, D); k/v: (B, KV, S, D).  Returns (B, H, S, D)."""
     B, H, S, D = q.shape
     KV = k.shape[1]
@@ -89,5 +91,5 @@ def flash_attention_bhsd(q, k, v, *, window: Optional[int] = None,
         ],
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

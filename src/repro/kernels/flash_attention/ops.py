@@ -13,7 +13,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_bhsd
 @functools.partial(jax.jit, static_argnames=("window", "logit_softcap",
                                              "interpret"))
 def flash_attention(q, k, v, *, window: Optional[int] = None,
-                    logit_softcap: float = 0.0, interpret: bool = True):
+                    logit_softcap: float = 0.0, interpret=None):
     """q: (B, S, H, D), k/v: (B, S, KV, D) — the model-side layout."""
     qt = q.swapaxes(1, 2)
     kt = k.swapaxes(1, 2)

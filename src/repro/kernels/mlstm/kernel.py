@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG = -1.0e30
 
 
@@ -79,7 +81,7 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, ig_ref, fg_ref, o_ref,
 
 
 def mlstm_chunkwise_bh(q, k, v, i_pre, f_pre, *, chunk: int = 128,
-                       interpret: bool = True):
+                       interpret=None):
     """q,k,v: (BH, S, dh); gates: (BH, S).  Returns h: (BH, S, dh)."""
     BH, S, dh = q.shape
     chunk = min(chunk, S)
@@ -102,5 +104,5 @@ def mlstm_chunkwise_bh(q, k, v, i_pre, f_pre, *, chunk: int = 128,
             pltpu.VMEM((1, dh), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, i_pre, f_pre)

@@ -11,7 +11,7 @@ from repro.kernels.mlstm.kernel import mlstm_chunkwise_bh
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def mlstm_chunkwise(q, k, v, i_pre, f_pre, *, chunk: int = 128,
-                    interpret: bool = True):
+                    interpret=None):
     """q,k,v: (B, S, H, dh); i_pre,f_pre: (B, S, H).  Returns (B, S, H, dh)."""
     B, S, H, dh = q.shape
 

@@ -4,7 +4,6 @@ here — the raw kernel entry points refuse ragged pools."""
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -13,27 +12,6 @@ from repro.kernels.pool_mlp.kernel import (pool_mlp_features_pallas,
                                            pool_mlp_pallas)
 
 _KEYS = ("w0", "b0", "w1", "b1", "w2", "b2", "w3", "b3", "w4", "b4")
-
-# Backends with a Pallas lowering for this kernel: Mosaic on TPU (the tuned
-# target) and Triton on GPU (EXPERIMENTAL: the batched-einsum body is
-# untested against Triton's dot lowering — if it fails to lower on your
-# GPU, set REPRO_POOL_KERNEL_INTERPRET=1 to force interpret mode without a
-# code change).  Everywhere else (CPU tests, exotic backends) the kernel
-# runs in interpret mode.
-_COMPILED_BACKENDS = ("tpu", "gpu", "cuda", "rocm")
-
-
-def _resolve_interpret(interpret):
-    """None -> compiled kernel on TPU and GPU, interpret-mode emulation
-    elsewhere (interpret keeps CPU tests running).  The
-    REPRO_POOL_KERNEL_INTERPRET env var (0/1) overrides the backend
-    heuristic either way."""
-    env = os.environ.get("REPRO_POOL_KERNEL_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "")
-    if interpret is None:
-        return jax.default_backend() not in _COMPILED_BACKENDS
-    return interpret
 
 
 def _padded_weights(pool_stacked, BP: int):
@@ -57,13 +35,12 @@ def pool_mlp_errors(pool_stacked, xd, y, *, block_pool: int = 8,
                     interpret=None):
     """pool_stacked: dict of stacked Table-4 head params (ns leading dim);
     xd: (R, w); y: (R,).  Returns (ns,) mean squared errors (Eq. 7)."""
-    interpret = _resolve_interpret(interpret)
     ns = pool_stacked["w0"].shape[0]
     BP = min(block_pool, ns)
     errs = pool_mlp_pallas(xd, y, _padded_weights(pool_stacked, BP),
                            block_pool=BP, interpret=interpret)
     # Non-finite scores (NaN probes or poisoned pool rows) pin to +inf so
-    # argmin never selects them — identical to the vmap fallback's pinning,
+    # argmin never selects them — identical to the vmap path's pinning,
     # and an exact pass-through for finite errors.
     errs = jnp.where(jnp.isfinite(errs), errs, jnp.inf)
     return errs[:ns]
@@ -121,10 +98,10 @@ def pool_mlp_errors_features(pool_stacked, xd_feats, y, *,
     """Score the whole pool against EVERY target feature's probe batch.
 
     xd_feats: (nf, R, w) — one (R, w) dense-vector batch per target feature;
-    y: (R,).  Returns (nf, ns).  ONE pallas_call whose grid walks
-    (feature, pool-block) cells — nf sweeps in a single kernel launch, not a
-    trace-time Python loop of nf launches."""
-    interpret = _resolve_interpret(interpret)
+    y: (R,).  Returns (nf, ns).  ONE pallas_call whose grid walks pool
+    blocks, each cell scoring its heads against all nf probe batches — nf
+    sweeps in a single kernel launch, not a trace-time Python loop of nf
+    launches."""
     ns = pool_stacked["w0"].shape[0]
     BP = min(block_pool, ns)
     errs = pool_mlp_features_pallas(xd_feats, y,

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 
 def _rglru_kernel(a_ref, b_ref, o_ref, h_scratch):
     ci = pl.program_id(1)
@@ -40,7 +42,7 @@ def _rglru_kernel(a_ref, b_ref, o_ref, h_scratch):
     h_scratch[0] = h[-1]
 
 
-def rglru_scan_pallas(a, b, *, chunk: int = 256, interpret: bool = True):
+def rglru_scan_pallas(a, b, *, chunk: int = 256, interpret=None):
     """a, b: (B, S, d).  Returns h: (B, S, d) with h_t = a_t h_{t-1} + b_t."""
     B, S, d = a.shape
     chunk = min(chunk, S)
@@ -55,5 +57,5 @@ def rglru_scan_pallas(a, b, *, chunk: int = 256, interpret: bool = True):
         out_specs=pl.BlockSpec((1, chunk, d), lambda bi, ci: (bi, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, d), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)

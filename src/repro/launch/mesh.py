@@ -6,6 +6,14 @@ jax device state (device count is locked at first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the model zoo places its arrays
+    with ``in_shardings`` / ``with_sharding_constraint`` and lets the
+    compiler propagate the rest (JAX's default mesh axes are Explicit)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,14 +25,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over the real local devices (tests / smoke runs)."""
     n = len(jax.devices())
     data = min(data, n)
-    return jax.make_mesh((data, max(1, min(model, n // data))), ("data", "model"))
+    return _make_mesh((data, max(1, min(model, n // data))), ("data", "model"))
 
 
 def mesh_axis_sizes(mesh) -> dict:

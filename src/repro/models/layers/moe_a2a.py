@@ -164,12 +164,8 @@ def moe_apply_a2a(params, x, cfg: MoEConfig, act: str, mesh,
     in_specs = (P("data", None, None), P(), P(ep_axes, None, None),
                 P(ep_axes, None, None), P(ep_axes, None, None))
     out_specs = (P("data", None, None), P())
-    # jax.shard_map / check_vma is the jax>=0.7 spelling; this repo runs on
-    # jax 0.4, whose entry point is the experimental one (same semantics,
-    # check_rep spelling)
-    from jax.experimental.shard_map import shard_map
-    body_mapped = shard_map(body, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False)
+    body_mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                out_specs=out_specs, check_vma=False)
     out, aux = body_mapped(x, params["router"], params["wg"], params["wu"],
                            params["wd"])
     if cfg.n_shared_experts:

@@ -296,11 +296,11 @@ def test_cohorted_mesh_rejects_non_divisible_cohorts():
 # ---------------------------------------------------------------------------
 
 _SUBPROCESS = r"""
-import json
+import collections, gc, json
 import numpy as np
 import jax
 assert jax.device_count() == 4, jax.devices()
-from repro.core.federation import Federation
+from repro.core.federation import Callback, Federation
 from repro.core import mesh_federation as MF
 from repro.core.hfl import FederatedClient, HFLConfig
 
@@ -319,11 +319,25 @@ def mk_clients(cfg, seed0=100):
                                    mk(30), jax.random.PRNGKey(i)))
     return out
 
+class LiveBytes(Callback):
+    # live-array bytes per device while the mesh fit's state is live
+    def on_epoch_end(self, fed, epoch, val, active):
+        per = collections.Counter()
+        for a in jax.live_arrays():
+            for s in a.addressable_shards:
+                per[s.device] += s.data.nbytes
+        self.per_device = [per[d] for d in jax.devices()]
+
 cfg = HFLConfig(mode="always", epochs=3, R=20)
 h_oracle = Federation(mk_clients(cfg), cfg, engine="sequential").fit()
+gc.collect()
+live = LiveBytes()
 fed = Federation(mk_clients(cfg), cfg, engine="batched",
-                 mesh=MF.make_mesh())
+                 mesh=MF.make_mesh(), callbacks=[live])
 h_mesh = fed.fit()
+# no cohort stack or client copy sits whole on device 0
+balanced = (max(live.per_device) - min(live.per_device)
+            <= 0.01 * max(live.per_device))
 st = fed.dispatch_stats
 assert st["devices"] == 4 and st["cohorts"] == 2, st
 assert st["path"] == "fused" and st["dispatches_per_epoch"] == 1.0, st
@@ -335,14 +349,16 @@ val_close = all(np.allclose(h_oracle[n]["val"], h_mesh[n]["val"],
                             rtol=1e-6, atol=1e-6) for n in h_oracle)
 print("RESULT " + json.dumps({"sel_identical": sel_identical,
                               "rounds_identical": rounds_identical,
-                              "val_close": val_close}))
+                              "val_close": val_close,
+                              "bytes_balanced": balanced}))
 """
 
 
 def test_mixed_population_on_forced_4_device_mesh():
     """ISSUE 5 acceptance: a mixed-nf ragged population client-shards its
     cohorts over a genuine 4-device `clients` mesh with selections
-    identical to the sequential oracle."""
+    identical to the sequential oracle, and live bytes equal on every
+    device during the fit."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=4").strip()
@@ -356,7 +372,7 @@ def test_mixed_population_on_forced_4_device_mesh():
     assert line, out.stdout
     res = json.loads(line[-1][len("RESULT "):])
     assert res == {"sel_identical": True, "rounds_identical": True,
-                   "val_close": True}
+                   "val_close": True, "bytes_balanced": True}
 
 
 # ---------------------------------------------------------------------------

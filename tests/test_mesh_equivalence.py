@@ -33,7 +33,8 @@ state = steps.init_state(cfg, opt, jax.random.PRNGKey(0))
 pipe = TokenPipeline(LMPipelineConfig(batch=8, seq_len=32,
                                       vocab_size=cfg.vocab_size,
                                       n_patches=8), cfg)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 with mesh:
     st_specs = named(steps.state_pspecs(cfg, opt, mesh), mesh)
     from repro.configs.base import INPUT_SHAPES, InputShape
@@ -51,17 +52,7 @@ print("LOSSES", losses)
 """
 
 
-@pytest.mark.parametrize(
-    "arch",
-    ["qwen3-0.6b",
-     pytest.param("olmoe-1b-7b", marks=pytest.mark.xfail(
-         strict=False,
-         reason="TRACKING (pre-existing at PR-4 HEAD): sharded olmoe losses "
-                "drift ~0.8% from single-device — MoE top-k capacity "
-                "dropping reorders tokens under the (2,4) mesh, so "
-                "different tokens are dropped, a routing-semantics gap "
-                "(not float noise; needs a deterministic cross-shard drop "
-                "order in models/layers/moe.py)"))])
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "olmoe-1b-7b"])
 def test_sharded_equals_single_device(arch):
     # single-device reference
     cfg = smoke_config(arch)
@@ -79,7 +70,8 @@ def test_sharded_equals_single_device(arch):
 
     proc = subprocess.run(
         [sys.executable, "-c", _SCRIPT.format(arch=arch)],
-        env={"PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin"},
+        env={"PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin",
+             "JAX_PLATFORMS": "cpu"},
         capture_output=True, text=True, timeout=560)
     assert "LOSSES" in proc.stdout, proc.stdout + proc.stderr[-2000:]
     got = eval(proc.stdout.split("LOSSES", 1)[1].strip())

@@ -183,13 +183,22 @@ def test_schema_derived_pspecs_partition_client_axis():
 # ---------------------------------------------------------------------------
 
 _SUBPROCESS = r"""
-import json, os, sys, tempfile
+import collections, gc, json, os, sys, tempfile
 import numpy as np
 import jax
 assert jax.device_count() == 4, jax.devices()
 from repro.core import mesh_federation as MF
-from repro.core.federation import Federation
+from repro.core.federation import Callback, Federation
 from repro.core.hfl import FederatedClient, HFLConfig
+
+class LiveBytes(Callback):
+    # live-array bytes per device while the mesh fit's state is live
+    def on_epoch_end(self, fed, epoch, val, active):
+        per = collections.Counter()
+        for a in jax.live_arrays():
+            for s in a.addressable_shards:
+                per[s.device] += s.data.nbytes
+        self.per_device = [per[d] for d in jax.devices()]
 
 def mk_clients(cfg, C=32, nf=2, n=40, seed0=100):
     out = []
@@ -206,8 +215,15 @@ cfg = HFLConfig(mode="always", epochs=3, R=20)
 mesh = MF.make_mesh()
 
 h_oracle = Federation(mk_clients(cfg), cfg, engine="batched").fit()
-fed = Federation(mk_clients(cfg), cfg, engine="batched", mesh=mesh)
+gc.collect()    # the oracle's arrays (a fit leaves a cycle via its sync)
+live = LiveBytes()
+fed = Federation(mk_clients(cfg), cfg, engine="batched", mesh=mesh,
+                 callbacks=[live])
 h_mesh = fed.fit()
+# no array of the fit sits whole on device 0: every device holds the same
+# share of the partitioned state plus the replicated pool
+balanced = (max(live.per_device) - min(live.per_device)
+            <= 0.01 * max(live.per_device))
 expect = {
     "engine": "batched", "path": "fused", "devices": 4, "cohorts": 1,
     "epochs": 3, "dispatches": 3, "dispatches_per_epoch": 1.0,
@@ -237,7 +253,8 @@ with tempfile.TemporaryDirectory() as d:
 
 print("RESULT " + json.dumps({"sel_identical": sel_identical,
                               "val_identical": val_identical,
-                              "ck_identical": ck_identical}))
+                              "ck_identical": ck_identical,
+                              "bytes_balanced": balanced}))
 """
 
 
@@ -264,10 +281,12 @@ def test_32_clients_on_forced_4_device_mesh():
     """ISSUE 4 acceptance: with XLA_FLAGS=--xla_force_host_platform_device_
     count=4, a 32-client population runs the fused epoch on a 4-device
     `clients` mesh with selections identical to the single-device oracle,
-    and Federation.save/restore round-trips the sharded state bit-exactly."""
+    Federation.save/restore round-trips the sharded state bit-exactly, and
+    live bytes are equal on every device during the fit (the clients' own
+    copies are not left whole on device 0)."""
     res = _run_forced_devices(_SUBPROCESS, 4)
     assert res == {"sel_identical": True, "val_identical": True,
-                   "ck_identical": True}
+                   "ck_identical": True, "bytes_balanced": True}
 
 
 # ---------------------------------------------------------------------------
