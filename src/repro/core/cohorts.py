@@ -292,7 +292,11 @@ def _hetero_epoch_body(lr: float, plan: CohortPlan,
     pool_age (C,) int32)`` at the padded geometry (padded features select
     -1, so they never count as picks) — appended LAST and therefore popped
     FIRST at every unpack site, before trust, before admission.
-    ``telemetry=None`` traces the byte-identical pre-telemetry graph."""
+    ``telemetry=None`` traces the byte-identical pre-telemetry graph.
+
+    The named scopes are those of ``federation._epoch_body``:
+    ``train_step`` (every cohort's step), ``policy_round`` (``eq7_score``
+    inside) and ``eval_best``."""
     opt = adam(lr)
     step = jax.vmap(functools.partial(_train_step, opt))
     evaluate = jax.vmap(_eval_mse)
@@ -319,12 +323,15 @@ def _hetero_epoch_body(lr: float, plan: CohortPlan,
             sub-round (shared by exchange and train-only rounds)."""
             params_t, opt_t = list(params_t), list(opt_t)
             for k, co in enumerate(plan.cohorts):
-                p2, o2, _ = step(params_t[k], opt_t[k], bx[k], bd[k], by[k])
-                if co.n_sub == plan.n_sub_max:
-                    params_t[k], opt_t[k] = p2, o2     # never a padded round
-                else:
-                    params_t[k] = _tree_select(live_r[k], p2, params_t[k])
-                    opt_t[k] = _tree_select(live_r[k], o2, opt_t[k])
+                with jax.named_scope("train_step"):
+                    p2, o2, _ = step(params_t[k], opt_t[k], bx[k], bd[k],
+                                     by[k])
+                    if co.n_sub == plan.n_sub_max:
+                        params_t[k], opt_t[k] = p2, o2  # never a padded round
+                    else:
+                        params_t[k] = _tree_select(live_r[k], p2,
+                                                   params_t[k])
+                        opt_t[k] = _tree_select(live_r[k], o2, opt_t[k])
             return params_t, opt_t
 
         def body(carry, inp):
@@ -359,22 +366,26 @@ def _hetero_epoch_body(lr: float, plan: CohortPlan,
                         xd_g = xd_g.at[idx].set(dk)
                         y_g = y_g.at[idx].set(gather(by[k]))
                 if secure:
-                    (new_heads, pool_heads, pool_age, chosen, rej,
-                     clip) = TR.secure_round(
-                        heads_g, pool_heads, pool_age, part_r, mask_e,
-                        corr_e, sub, feat_valid=feat_valid,
-                        sa=trust.secure_agg, dp=trust.dp, nf=max_nf,
-                        admission=admission)
+                    with jax.named_scope("policy_round"):
+                        (new_heads, pool_heads, pool_age, chosen, rej,
+                         clip) = TR.secure_round(
+                            heads_g, pool_heads, pool_age, part_r, mask_e,
+                            corr_e, sub, feat_valid=feat_valid,
+                            sa=trust.secure_agg, dp=trust.dp, nf=max_nf,
+                            admission=admission)
                     tstats = (clip, jnp.zeros((C,), bool))
                 else:
-                    out = _policy_round_body(
-                        heads_g, pool_heads, pool_age, xd_g, y_g, part_r,
-                        sub, nf=max_nf, policies=policies,
-                        use_kernel=use_kernel, feat_valid=feat_valid,
-                        shard=shard, admission=admission, trust=sel_trust,
-                        trust_sig=(trust_arrays if sel_trust is not None
-                                   and sel_trust.watermark is not None
-                                   else None), telemetry=telemetry)
+                    with jax.named_scope("policy_round"):
+                        out = _policy_round_body(
+                            heads_g, pool_heads, pool_age, xd_g, y_g,
+                            part_r, sub, nf=max_nf, policies=policies,
+                            use_kernel=use_kernel, feat_valid=feat_valid,
+                            shard=shard, admission=admission,
+                            trust=sel_trust,
+                            trust_sig=(trust_arrays
+                                       if sel_trust is not None
+                                       and sel_trust.watermark is not None
+                                       else None), telemetry=telemetry)
                     if telemetry is not None:
                         scores = out[-1]
                         out = out[:-1]
@@ -477,16 +488,17 @@ def _hetero_epoch_body(lr: float, plan: CohortPlan,
         if do_eval:
             vs, new_bv, new_bp = [], [], []
             for k in range(K):
-                v = evaluate(params_t[k], val_xs_t[k], val_xd_t[k],
-                             val_y_t[k])                  # (local clients,)
-                improved = v < best_val_t[k]
-                new_bv.append(jnp.where(improved, v, best_val_t[k]))
-                n_loc = v.shape[0]
-                new_bp.append(jax.tree_util.tree_map(
-                    lambda b, p: jnp.where(
-                        improved.reshape((n_loc,) + (1,) * (p.ndim - 1)),
-                        p, b),
-                    best_params_t[k], params_t[k]))
+                with jax.named_scope("eval_best"):
+                    v = evaluate(params_t[k], val_xs_t[k], val_xd_t[k],
+                                 val_y_t[k])              # (local clients,)
+                    improved = v < best_val_t[k]
+                    new_bv.append(jnp.where(improved, v, best_val_t[k]))
+                    n_loc = v.shape[0]
+                    new_bp.append(jax.tree_util.tree_map(
+                        lambda b, p: jnp.where(
+                            improved.reshape((n_loc,) + (1,) * (p.ndim - 1)),
+                            p, b),
+                        best_params_t[k], params_t[k]))
                 vs.append(v)
             best_val_t, best_params_t = tuple(new_bv), tuple(new_bp)
             v_t = tuple(vs)
@@ -678,37 +690,39 @@ def _fit_cohorted(fed, n_epochs: int, cbs) -> None:
                               r.dtype)], 0)
         return r
 
-    rounds_t, val_t = [], []
-    params_l, opt_l, bv_l, bp_l = [], [], [], []
-    for co in plan.cohorts:
-        cs = [clients[i] for i in co.members]
-        stacked = tuple(jnp.stack([np.asarray(c.train[j]) for c in cs])
-                        for j in range(3))
-        rounds_t.append(tuple(rounds_axis(t, co.n_sub) for t in stacked))
-        val_t.append(tuple(jnp.stack([np.asarray(c.valid[j]) for c in cs])
-                           for j in range(3)))
-        params_l.append(_stack_trees([c.params for c in cs]))
-        opt_l.append(_stack_trees([c.opt_state for c in cs]))
-        bv_l.append(jnp.asarray([c.best_val for c in cs], jnp.float32))
-        bp_l.append(_stack_trees([c.best_params for c in cs]))
-    rounds_t, val_t = tuple(rounds_t), tuple(val_t)
-    params_t, opt_t = tuple(params_l), tuple(opt_l)
-    best_val_t, best_params_t = tuple(bv_l), tuple(bp_l)
-    del stacked, params_l, opt_l, bv_l, bp_l
-
-    pool_heads = stack_hetero_pool(fed.pool, names, plan.nfs, plan.max_nf)
-    pool_age = jnp.asarray([fed.pool.age_of(n_) for n_ in names], jnp.int32)
+    # telemetry layer: `tele` = the enabled plan iff the in-graph series is
+    # on (static jit arg; None traces the uninstrumented graph), `rec` =
+    # the host-side flight recorder
+    tele = fed._tele_rounds()
+    rec = fed._recorder
+    with TEL.span(rec, "restack"):
+        rounds_t, val_t = [], []
+        params_l, opt_l, bv_l, bp_l = [], [], [], []
+        for co in plan.cohorts:
+            cs = [clients[i] for i in co.members]
+            rounds_t.append(tuple(
+                rounds_axis(jnp.stack([np.asarray(c.train[j]) for c in cs]),
+                            co.n_sub) for j in range(3)))
+            val_t.append(tuple(jnp.stack([np.asarray(c.valid[j])
+                                          for c in cs]) for j in range(3)))
+            params_l.append(_stack_trees([c.params for c in cs]))
+            opt_l.append(_stack_trees([c.opt_state for c in cs]))
+            bv_l.append(jnp.asarray([c.best_val for c in cs], jnp.float32))
+            bp_l.append(_stack_trees([c.best_params for c in cs]))
+        rounds_t, val_t = tuple(rounds_t), tuple(val_t)
+        params_t, opt_t = tuple(params_l), tuple(opt_l)
+        best_val_t, best_params_t = tuple(bv_l), tuple(bp_l)
+        del params_l, opt_l, bv_l, bp_l
+        pool_heads = stack_hetero_pool(fed.pool, names, plan.nfs,
+                                       plan.max_nf)
+        pool_age = jnp.asarray([fed.pool.age_of(n_) for n_ in names],
+                               jnp.int32)
     use_kernel = cfg.use_pool_kernel
     lut = hetero_selection_lut(names, plan.nfs, plan.max_nf)
     admission = fed._admission()
     smask = fed._straggler_mask
     trust = fed._trust
     secure = trust is not None and trust.secure_agg is not None
-    # telemetry layer: `tele` = the enabled plan iff the in-graph series is
-    # on (static jit arg; None traces the uninstrumented graph), `rec` =
-    # the host-side flight recorder
-    tele = fed._tele_rounds()
-    rec = fed._recorder
     # host templates/derivations the trust layer needs, at the PADDED
     # geometry (masks and signatures ride the (C, max_nf, ...) union)
     head_tmpl = TR.pad_rows(jax.tree_util.tree_map(
@@ -828,22 +842,24 @@ def _fit_cohorted(fed, n_epochs: int, cbs) -> None:
     def sync():
         """Write the per-cohort loop state back into the clients / pool /
         rng — after the loop, and on demand for mid-fit checkpoints."""
-        ages = np.asarray(pool_age)
-        for k, co in enumerate(plan.cohorts):
-            bv = np.asarray(best_val_t[k])
-            for r, i in enumerate(co.members):
-                c = clients[i]
-                c.params = _tree_row(params_t[k], r)
-                c.opt_state = _tree_row(opt_t[k], r)
-                c.val_history = histories[i]
-                c.best_val = float(bv[r])
-                c.best_params = _tree_row(best_params_t[k], r)
-        for i, c in enumerate(clients):
-            row = jax.tree_util.tree_map(
-                lambda p: p[i, :plan.nfs[i]], pool_heads)
-            fed.pool.publish(c.name, row, plan.nfs[i], age=int(ages[i]))
-            fed.n_rounds[c.name] = base_rounds[c.name] + int(n_rounds[i])
-        fed._key = key
+        with TEL.span(rec, "writeback"):
+            ages = np.asarray(pool_age)
+            for k, co in enumerate(plan.cohorts):
+                bv = np.asarray(best_val_t[k])
+                for r, i in enumerate(co.members):
+                    c = clients[i]
+                    c.params = _tree_row(params_t[k], r)
+                    c.opt_state = _tree_row(opt_t[k], r)
+                    c.val_history = histories[i]
+                    c.best_val = float(bv[r])
+                    c.best_params = _tree_row(best_params_t[k], r)
+            for i, c in enumerate(clients):
+                row = jax.tree_util.tree_map(
+                    lambda p: p[i, :plan.nfs[i]], pool_heads)
+                fed.pool.publish(c.name, row, plan.nfs[i], age=int(ages[i]))
+                fed.n_rounds[c.name] = (base_rounds[c.name]
+                                        + int(n_rounds[i]))
+            fed._key = key
 
     fed._sync = sync
     for _ in range(n_epochs):
@@ -879,16 +895,15 @@ def _fit_cohorted(fed, n_epochs: int, cbs) -> None:
         if fused:
             epoch_fn = make_epoch_fn(do_federate, True, k_ex)
             act_rows = part_np[exch] if do_federate else part_np[:0]
+            args = (*state, tuple(r[0] for r in rounds_t),
+                    tuple(r[1] for r in rounds_t),
+                    tuple(r[2] for r in rounds_t), part, tick, live,
+                    tuple(v[0] for v in val_t), tuple(v[1] for v in val_t),
+                    tuple(v[2] for v in val_t), *trust_args(act_rows))
+            if rec is not None:
+                rec.note_program(epoch_fn, *args)
             with TEL.span(rec, "dispatch", epoch=epoch, path="fused"):
-                out = epoch_fn(*state,
-                               tuple(r[0] for r in rounds_t),
-                               tuple(r[1] for r in rounds_t),
-                               tuple(r[2] for r in rounds_t),
-                               part, tick, live,
-                               tuple(v[0] for v in val_t),
-                               tuple(v[1] for v in val_t),
-                               tuple(v[2] for v in val_t),
-                               *trust_args(act_rows))
+                out = epoch_fn(*args)
             if tele is not None:   # telemetry rides LAST: pop it first
                 tele_out, out = out[-1], out[:-1]
             if trust is not None:
@@ -980,9 +995,16 @@ def _fit_cohorted(fed, n_epochs: int, cbs) -> None:
                 else None
         (params_t, opt_t, pool_heads, pool_age, key, best_val_t,
          best_params_t) = state
-        with TEL.span(rec, "exchange", epoch=epoch):
-            if do_federate and chosen is not None:
-                ch_np = np.asarray(chosen)      # (rounds, C, max_nf)
+        with TEL.span(rec, "readback", epoch=epoch):
+            # ONE device->host materialization of the epoch's results
+            v_all = np.empty(C, np.float64)
+            for k, co in enumerate(plan.cohorts):
+                v_all[np.asarray(co.members)] = np.asarray(v_t[k],
+                                                           np.float64)
+            ch_np = (np.asarray(chosen)         # (rounds, C, max_nf)
+                     if do_federate and chosen is not None else None)
+        with TEL.span(rec, "record", epoch=epoch):
+            if ch_np is not None:
                 for ch in ch_np:
                     for i in range(C):
                         if ch[i][0] >= 0:
@@ -991,23 +1013,20 @@ def _fit_cohorted(fed, n_epochs: int, cbs) -> None:
                                 lut[i, ch[i][:nf_i]].tolist())
             if tele is not None and tele_out is not None:
                 rec.record_epoch_rounds(epoch, tele_out, active)
-        if fused:
-            n_rounds += part_np[exch].sum(axis=0)
-        if rec is not None:
-            done = int(part_np[exch].sum())
-            if done:
-                rec.count("client_rounds", done)
-        # refresh the live counters each epoch (idempotent with sync())
-        for i, nm in enumerate(names):
-            fed.n_rounds[nm] = base_rounds[nm] + int(n_rounds[i])
-        if do_federate:
-            exchange_rounds += n_exch_epoch
-            pool_bytes += n_exch_epoch * exch_bytes
-        v_all = np.empty(C, np.float64)
-        for k, co in enumerate(plan.cohorts):
-            v_all[np.asarray(co.members)] = np.asarray(v_t[k], np.float64)
-        for i in range(C):
-            histories[i].append(float(v_all[i]))
+            if fused:
+                n_rounds += part_np[exch].sum(axis=0)
+            if rec is not None:
+                done = int(part_np[exch].sum())
+                if done:
+                    rec.count("client_rounds", done)
+            # refresh the live counters each epoch (idempotent with sync())
+            for i, nm in enumerate(names):
+                fed.n_rounds[nm] = base_rounds[nm] + int(n_rounds[i])
+            if do_federate:
+                exchange_rounds += n_exch_epoch
+                pool_bytes += n_exch_epoch * exch_bytes
+            for i in range(C):
+                histories[i].append(float(v_all[i]))
         fed.epoch += 1
         fed._mid_epoch = False
         for cb in cbs:

@@ -684,15 +684,16 @@ def _policy_round_body(heads, pool_heads, pool_age, xd_R, y_R, active, key,
             """Eq.-7 errors of ``pool_rows`` (full pool or a device chunk)
             against client i's probe batch — row-independent, so a chunk
             sweep equals the corresponding slice of the full sweep."""
-            xd_i = jnp.moveaxis(xd_R[i], 1, 0)          # (nf, R, w)
-            if use_kernel:
-                if valid_rows is not None:
-                    return pool_ops.pool_mlp_errors_shard(pool_rows, xd_i,
-                                                          y_R[i], valid_rows)
-                return pool_ops.pool_mlp_errors_features(pool_rows, xd_i,
-                                                         y_R[i])
-            return jax.vmap(
-                lambda xf: pool_errors(pool_rows, xf, y_R[i]))(xd_i)
+            with jax.named_scope("eq7_score"):
+                xd_i = jnp.moveaxis(xd_R[i], 1, 0)          # (nf, R, w)
+                if use_kernel:
+                    if valid_rows is not None:
+                        return pool_ops.pool_mlp_errors_shard(
+                            pool_rows, xd_i, y_R[i], valid_rows)
+                    return pool_ops.pool_mlp_errors_features(
+                        pool_rows, xd_i, y_R[i])
+                return jax.vmap(
+                    lambda xf: pool_errors(pool_rows, xf, y_R[i]))(xd_i)
 
         valid_arg = valid_flat if feat_valid is not None else None
         if sel.needs_errors:
@@ -1016,7 +1017,11 @@ def _epoch_body(lr: float, nf: int, policies: FederationPolicies,
     snapshots)`` — still inside the same single dispatch.  Unpack order at
     every call site: telemetry pops FIRST (it is appended last), then
     trust, then admission.  ``telemetry=None`` traces the byte-identical
-    pre-instrumentation graph."""
+    pre-instrumentation graph.
+
+    The computation carries the named scopes ``train_step``,
+    ``policy_round`` (``eq7_score`` inside) and ``eval_best``: metadata on
+    its ops, which a device profile reads, and nothing else."""
     opt = adam(lr)
     step = jax.vmap(functools.partial(_train_step, opt))
     evaluate = jax.vmap(_eval_mse)
@@ -1046,27 +1051,34 @@ def _epoch_body(lr: float, nf: int, policies: FederationPolicies,
                 xs_b, xd_b, y_b = batch
             if do_federate and not secure:  # secure needs no probe gathers
                 xd_g, y_g = gather(xd_b), gather(y_b)   # overlaps the step
-            params, opt_state, _ = step(params, opt_state, xs_b, xd_b, y_b)
+            with jax.named_scope("train_step"):
+                params, opt_state, _ = step(params, opt_state, xs_b, xd_b,
+                                            y_b)
             if do_federate:
                 if bounded:
                     pool_age = pool_age + 1
                 key, sub = jax.random.split(key)
                 if secure:
-                    (new_heads, pool_heads, pool_age, chosen, rej,
-                     clip) = TR.secure_round(
-                        gather(params["heads"]), pool_heads, pool_age,
-                        active, mask_e, corr_e, sub, sa=trust.secure_agg,
-                        dp=trust.dp, nf=nf, admission=admission)
+                    with jax.named_scope("policy_round"):
+                        (new_heads, pool_heads, pool_age, chosen, rej,
+                         clip) = TR.secure_round(
+                            gather(params["heads"]), pool_heads, pool_age,
+                            active, mask_e, corr_e, sub,
+                            sa=trust.secure_agg, dp=trust.dp, nf=nf,
+                            admission=admission)
                     tstats = (clip, jnp.zeros((C,), bool))
                 else:
-                    out = _policy_round_body(
-                        gather(params["heads"]), pool_heads, pool_age,
-                        xd_g, y_g, active, sub, nf=nf,
-                        policies=policies, use_kernel=use_kernel,
-                        shard=shard, admission=admission, trust=sel_trust,
-                        trust_sig=(trust_arrays if sel_trust is not None
-                                   and sel_trust.watermark is not None
-                                   else None), telemetry=telemetry)
+                    with jax.named_scope("policy_round"):
+                        out = _policy_round_body(
+                            gather(params["heads"]), pool_heads, pool_age,
+                            xd_g, y_g, active, sub, nf=nf,
+                            policies=policies, use_kernel=use_kernel,
+                            shard=shard, admission=admission,
+                            trust=sel_trust,
+                            trust_sig=(trust_arrays
+                                       if sel_trust is not None
+                                       and sel_trust.watermark is not None
+                                       else None), telemetry=telemetry)
                     if telemetry is not None:
                         scores = out[-1]
                         out = out[:-1]
@@ -1106,7 +1118,9 @@ def _epoch_body(lr: float, nf: int, policies: FederationPolicies,
         def train_only(carry, batch):
             params, opt_state, pool_heads, pool_age, key = carry
             xs_b, xd_b, y_b = batch
-            params, opt_state, _ = step(params, opt_state, xs_b, xd_b, y_b)
+            with jax.named_scope("train_step"):
+                params, opt_state, _ = step(params, opt_state, xs_b, xd_b,
+                                            y_b)
             return (params, opt_state, pool_heads, pool_age, key), None
 
         carry = (params, opt_state, pool_heads, pool_age, key)
@@ -1163,14 +1177,16 @@ def _epoch_body(lr: float, nf: int, policies: FederationPolicies,
             chosen, rejected, tstats = ys, None, None
         (params, opt_state, pool_heads, pool_age, key) = carry
         if do_eval:
-            v = evaluate(params, val_xs, val_xd, val_y)  # (local clients,)
-            improved = v < best_val
-            best_val = jnp.where(improved, v, best_val)
-            n_loc = v.shape[0]
-            best_params = jax.tree_util.tree_map(
-                lambda b, p: jnp.where(
-                    improved.reshape((n_loc,) + (1,) * (p.ndim - 1)), p, b),
-                best_params, params)
+            with jax.named_scope("eval_best"):
+                v = evaluate(params, val_xs, val_xd, val_y)  # (local,)
+                improved = v < best_val
+                best_val = jnp.where(improved, v, best_val)
+                n_loc = v.shape[0]
+                best_params = jax.tree_util.tree_map(
+                    lambda b, p: jnp.where(
+                        improved.reshape((n_loc,) + (1,) * (p.ndim - 1)),
+                        p, b),
+                    best_params, params)
         else:
             v = None
         out = (params, opt_state, pool_heads, pool_age, key, best_val,
@@ -1257,14 +1273,13 @@ def _fit_batched(fed: "Federation", n_epochs: int, cbs) -> None:
     nf = clients[0].nf
     cfg, pol = fed.cfg, fed.policies
     R = fed.schedule.R
-
-    xs = jnp.stack([np.asarray(c.train[0]) for c in clients])
-    xd = jnp.stack([np.asarray(c.train[1]) for c in clients])
-    y = jnp.stack([np.asarray(c.train[2]) for c in clients])
-    val = tuple(jnp.stack([np.asarray(c.valid[k]) for c in clients])
-                for k in range(3))
-    n = int(y.shape[1])
-    n_sub = fed.schedule.sub_rounds(n)
+    # telemetry layer (core/telemetry.py): `tele` is the enabled plan iff
+    # its in-graph per-round series is on (a static jit argument, so
+    # tele=None traces the byte-identical pre-instrumentation graph); `rec`
+    # is the host-side flight recorder (spans + counters + round events)
+    tele = fed._tele_rounds()
+    rec = fed._recorder
+    n_sub = fed.schedule.sub_rounds(int(np.shape(clients[0].train[2])[0]))
 
     def rounds_axis(t):
         """(C, n, ...) -> (n_sub, C, R, ...): the schedule's R-slices stacked
@@ -1274,27 +1289,28 @@ def _fit_batched(fed: "Federation", n_epochs: int, cbs) -> None:
         return jnp.moveaxis(
             t[:, :m].reshape((C, n_sub, R) + t.shape[2:]), 1, 0)
 
-    xs_r, xd_r, y_r = rounds_axis(xs), rounds_axis(xd), rounds_axis(y)
-    del xs, xd, y
-
-    params = _stack_trees([c.params for c in clients])
-    opt_state = _stack_trees([c.opt_state for c in clients])
-    # pool state comes from the canonical HeadPool (a fresh fit sees the
-    # initial publication; a restored fit sees the checkpointed pool)
-    pool_heads = stack_pool(fed.pool, names, nf)
-    pool_age = jnp.asarray([fed.pool.age_of(n_) for n_ in names], jnp.int32)
+    with TEL.span(rec, "restack"):
+        xs_r, xd_r, y_r = (
+            rounds_axis(jnp.stack([np.asarray(c.train[k]) for c in clients]))
+            for k in range(3))
+        val = tuple(jnp.stack([np.asarray(c.valid[k]) for c in clients])
+                    for k in range(3))
+        params = _stack_trees([c.params for c in clients])
+        opt_state = _stack_trees([c.opt_state for c in clients])
+        # pool state comes from the canonical HeadPool (a fresh fit sees
+        # the initial publication; a restored fit sees the checkpointed
+        # pool)
+        pool_heads = stack_pool(fed.pool, names, nf)
+        pool_age = jnp.asarray([fed.pool.age_of(n_) for n_ in names],
+                               jnp.int32)
+        best_val = jnp.asarray([c.best_val for c in clients], jnp.float32)
+        best_params = _stack_trees([c.best_params for c in clients])
     use_kernel = cfg.use_pool_kernel
     lut = _selection_lut(names, nf)
     admission = fed._admission()
     smask = fed._straggler_mask
     trust = fed._trust
     secure = trust is not None and trust.secure_agg is not None
-    # telemetry layer (core/telemetry.py): `tele` is the enabled plan iff
-    # its in-graph per-round series is on (a static jit argument, so
-    # tele=None traces the byte-identical pre-instrumentation graph); `rec`
-    # is the host-side flight recorder (spans + counters + round events)
-    tele = fed._tele_rounds()
-    rec = fed._recorder
     # host templates/derivations the trust layer needs (captured before the
     # stacked state is donated away)
     head_tmpl = jax.tree_util.tree_map(
@@ -1321,8 +1337,6 @@ def _fit_batched(fed: "Federation", n_epochs: int, cbs) -> None:
         C, nf, C * nf, pol.selection) if fed._exec_mesh() is not None else 0
 
     histories = [list(c.val_history) for c in clients]
-    best_val = jnp.asarray([c.best_val for c in clients], jnp.float32)
-    best_params = _stack_trees([c.best_params for c in clients])
     # device-resident learnable state for this fit (the participation
     # orchestrator's gather/scatter unit and its bounded-working-set meter)
     state_bytes = (_tree_bytes(params) + _tree_bytes(opt_state)
@@ -1408,18 +1422,20 @@ def _fit_batched(fed: "Federation", n_epochs: int, cbs) -> None:
         """Write the stacked loop state back into the clients / pool / rng —
         run after the loop, and on demand when a callback checkpoints the
         federation mid-fit (Federation.save calls this hook)."""
-        ages = np.asarray(pool_age)
-        bv = np.asarray(best_val)
-        for i, c in enumerate(clients):
-            c.params = _tree_row(params, i)
-            c.opt_state = _tree_row(opt_state, i)
-            c.val_history = histories[i]
-            c.best_val = float(bv[i])
-            c.best_params = _tree_row(best_params, i)
-            fed.pool.publish(c.name, _tree_row(pool_heads, i), nf,
-                             age=int(ages[i]))
-            fed.n_rounds[c.name] = base_rounds[c.name] + int(n_rounds[i])
-        fed._key = key
+        with TEL.span(rec, "writeback"):
+            ages = np.asarray(pool_age)
+            bv = np.asarray(best_val)
+            for i, c in enumerate(clients):
+                c.params = _tree_row(params, i)
+                c.opt_state = _tree_row(opt_state, i)
+                c.val_history = histories[i]
+                c.best_val = float(bv[i])
+                c.best_params = _tree_row(best_params, i)
+                fed.pool.publish(c.name, _tree_row(pool_heads, i), nf,
+                                 age=int(ages[i]))
+                fed.n_rounds[c.name] = (base_rounds[c.name]
+                                        + int(n_rounds[i]))
+            fed._key = key
 
     fed._sync = sync
     for _ in range(n_epochs):
@@ -1437,9 +1453,12 @@ def _fit_batched(fed: "Federation", n_epochs: int, cbs) -> None:
         fed._mid_epoch = True
         if fused:
             epoch_fn = make_epoch_fn(do_federate, True, k_ex)
+            args = (*state, xs_r, xd_r, y_r, active_dev, *val,
+                    *trust_args(active, n_exch_epoch))
+            if rec is not None:
+                rec.note_program(epoch_fn, *args)
             with TEL.span(rec, "dispatch", epoch=epoch, path="fused"):
-                out = epoch_fn(*state, xs_r, xd_r, y_r, active_dev, *val,
-                               *trust_args(active, n_exch_epoch))
+                out = epoch_fn(*args)
             if tele is not None:   # telemetry rides LAST: pop it first
                 tele_out, out = out[-1], out[:-1]
             if trust is not None:
@@ -1519,31 +1538,34 @@ def _fit_batched(fed: "Federation", n_epochs: int, cbs) -> None:
                 else None
         (params, opt_state, pool_heads, pool_age, key, best_val,
          best_params) = state
-        with TEL.span(rec, "exchange", epoch=epoch):
+        with TEL.span(rec, "readback", epoch=epoch):
+            # ONE device->host materialization of the epoch's results
+            v = np.asarray(v, np.float64)
+            chosen = np.asarray(chosen) if do_federate else None
+        with TEL.span(rec, "record", epoch=epoch):
             if do_federate:
-                # ONE device->host materialization of the epoch's selections
-                for ch in np.asarray(chosen):
+                for ch in chosen:
                     for i in range(C):
                         if active[i] and ch[i][0] >= 0:
                             fed.selections[names[i]].append(
                                 lut[i, ch[i]].tolist())
             if tele is not None and tele_out is not None:
                 rec.record_epoch_rounds(epoch, tele_out, active)
-        if fused and active.any():   # chunked path counted per round above
-            n_rounds += active * n_exch_epoch
-        if rec is not None and active.any():
-            rec.count("client_rounds", int(active.sum()) * n_exch_epoch)
-        # refresh the live counters each epoch (idempotent with sync(), a
-        # handful of host ints) so epoch-boundary readers — VerboseLogger's
-        # throughput line — see current round counts without a device sync
-        for i, nm in enumerate(names):
-            fed.n_rounds[nm] = base_rounds[nm] + int(n_rounds[i])
-        if do_federate:
-            exchange_rounds += n_exch_epoch
-            pool_bytes += n_exch_epoch * exch_bytes
-        v = np.asarray(v, np.float64)
-        for i in range(C):
-            histories[i].append(float(v[i]))
+            if fused and active.any():   # chunked path counted per round
+                n_rounds += active * n_exch_epoch
+            if rec is not None and active.any():
+                rec.count("client_rounds", int(active.sum()) * n_exch_epoch)
+            # refresh the live counters each epoch (idempotent with sync(),
+            # a handful of host ints) so epoch-boundary readers —
+            # VerboseLogger's throughput line — see current round counts
+            # without a device sync
+            for i, nm in enumerate(names):
+                fed.n_rounds[nm] = base_rounds[nm] + int(n_rounds[i])
+            if do_federate:
+                exchange_rounds += n_exch_epoch
+                pool_bytes += n_exch_epoch * exch_bytes
+            for i in range(C):
+                histories[i].append(float(v[i]))
         fed.epoch += 1
         fed._mid_epoch = False
         for cb in cbs:
@@ -1829,43 +1851,47 @@ class Federation:
         cbs = list(self.callbacks)
         if verbose and not any(isinstance(cb, VerboseLogger) for cb in cbs):
             cbs.append(VerboseLogger())
-        for cb in cbs:
-            cb.on_fit_start(self)
-        if n:
-            dropped = {c.name: self.schedule.leftover(len(c.train[2]))
-                       for c in self.clients}
-            dropped = {k: v for k, v in dropped.items() if v}
-            if dropped:
-                warnings.warn(
-                    f"RoundSchedule(R={self.schedule.R}) drops the trailing "
-                    f"partial batch every epoch: {dropped} train events per "
-                    f"epoch are never trained on (train lengths are not "
-                    f"multiples of R); truncate to a multiple of R or pick "
-                    f"a divisor R to silence this", UserWarning,
-                    stacklevel=2)
-            with TEL.span(self._recorder, "fit", epochs=n,
-                          engine=self.engine):
-                if self.engine == "batched":
-                    _fit_batched(self, n, cbs)
-                else:
-                    _fit_sequential(self, n, cbs)
-        results = self.results()
-        for cb in cbs:
-            cb.on_fit_end(self, results)
+        # generation-2 collections during the fit become `gc` spans
+        with TEL.gc_spans(self._recorder):
+            for cb in cbs:
+                cb.on_fit_start(self)
+            if n:
+                dropped = {c.name: self.schedule.leftover(len(c.train[2]))
+                           for c in self.clients}
+                dropped = {k: v for k, v in dropped.items() if v}
+                if dropped:
+                    warnings.warn(
+                        f"RoundSchedule(R={self.schedule.R}) drops the "
+                        f"trailing partial batch every epoch: {dropped} "
+                        f"train events per epoch are never trained on "
+                        f"(train lengths are not multiples of R); truncate "
+                        f"to a multiple of R or pick a divisor R to "
+                        f"silence this", UserWarning, stacklevel=2)
+                with TEL.span(self._recorder, "fit", epochs=n,
+                              engine=self.engine):
+                    if self.engine == "batched":
+                        _fit_batched(self, n, cbs)
+                    else:
+                        _fit_sequential(self, n, cbs)
+            results = self.results()
+            for cb in cbs:
+                cb.on_fit_end(self, results)
         return results
 
     def results(self):
         """Per-client history in the legacy run_federated_training format."""
-        if self._sync is not None:   # mid-fit (batched executor)
-            self._sync()
-        test = self._test_mses()
-        return {c.name: {"val": list(c.val_history),
-                         "test": test[c.name],
-                         "rounds": self.n_rounds[c.name],
-                         "best_val": float(c.best_val),
-                         "selections": [list(s) for s in
-                                        self.selections[c.name]]}
-                for c in self.clients}
+        with TEL.span(self._recorder, "results"):
+            if self._sync is not None:   # mid-fit (batched executor)
+                self._sync()
+            with TEL.span(self._recorder, "test_pass"):
+                test = self._test_mses()
+            return {c.name: {"val": list(c.val_history),
+                             "test": test[c.name],
+                             "rounds": self.n_rounds[c.name],
+                             "best_val": float(c.best_val),
+                             "selections": [list(s) for s in
+                                            self.selections[c.name]]}
+                    for c in self.clients}
 
     def _test_mses(self) -> Dict[str, float]:
         """Best-params test MSE per client — ONE vmapped dispatch per cohort

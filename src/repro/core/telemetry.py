@@ -21,10 +21,22 @@ Three pieces, all optional and all zero-cost when absent:
       masked out of the recorded aggregates).
 
 * **FlightRecorder** — a bounded ring buffer (``collections.deque``) of
-  host-side events: ``span`` timings (``span("gather")`` /
-  ``span("dispatch")`` / ``span("exchange")`` / ``span("scatter")`` with
-  optional ``jax.profiler`` trace annotations behind ``plan.profile``),
-  the decoded per-round metric records, and a counter registry snapshot.
+  host-side events: ``span`` timings, with optional ``jax.profiler``
+  trace annotations behind ``plan.profile``, the decoded per-round metric
+  records, and a counter registry snapshot.  The batched fit driver (both
+  engines) opens ``fit``; inside it ``restack`` (stacking the population,
+  pool and best parameters), per epoch ``dispatch`` (the enqueue of the
+  fused epoch), ``readback`` (the blocking read of its validation MSEs
+  and choices) and ``record`` (selections, histories, round series), and
+  ``writeback`` (``sync()``); after it ``results`` with ``test_pass``
+  inside.  ``gc`` spans mark generation-2 collections during a fit.  The
+  participation orchestrator adds ``sample`` / ``gather`` / ``exchange``
+  / ``scatter`` per wave.  On the device, the fused epoch programs carry
+  the ``jax.named_scope`` names ``policy_round`` (with ``eq7_score``
+  inside), ``train_step`` and ``eval_best`` in their ops' ``op_name``
+  metadata; under ``profile`` the recorder keeps each epoch program's
+  instruction -> ``op_name`` map (``programs``), so a device profile's
+  bare op names can be put down to a layer.
   It serializes to JSONL, round-trips through checkpoint manifests
   (``to_json`` / ``from_json``) so resumed runs continue their trace, and
   ``tools/trace_export.py`` turns the event list into Chrome-trace /
@@ -32,7 +44,7 @@ Three pieces, all optional and all zero-cost when absent:
 
 * **MetricsRegistry schema** — the typed, documented catalog of every
   ``dispatch_stats`` name the engines emit (counter / gauge / histogram /
-  label, units, deprecation aliases), machine-readable via ``schema()``.
+  label, units), machine-readable via ``schema()``.
   ``benchmarks/fl_scale_bench.validate_payload`` validates result rows
   against this one catalog instead of a hand-rolled column list.
 """
@@ -41,7 +53,9 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import gc
 import json
+import re
 import time
 from typing import Dict, Iterable, List, Optional, Tuple
 
@@ -206,71 +220,30 @@ METRICS: Dict[str, MetricSpec] = {m.name: m for m in [
        "staleness-age distribution (quarantine sentinel masked)"),
 ]}
 
-#: Deprecated spellings -> canonical catalog names.  ``resolve_aliases``
-#: rewrites these (with a DeprecationWarning) so external consumers that
-#: grew their own names converge on the one schema.
-DEPRECATED_ALIASES: Dict[str, str] = {
-    "bytes_gathered": "pool_bytes_gathered",
-    "rejected_heads": "heads_rejected",
-    "dropped_clients": "clients_dropped",
-    "eps_spent": "epsilon_spent",
-    "epsilon": "epsilon_spent",
-    "wm_failures": "watermark_failures",
-    "throughput": "client_rounds_per_s",
-}
-
-
-def canonical_name(name: str) -> str:
-    """Resolve a (possibly deprecated) metric name to its catalog name."""
-    return DEPRECATED_ALIASES.get(name, name)
-
-
 def metric_spec(name: str) -> MetricSpec:
-    return METRICS[canonical_name(name)]
-
-
-def resolve_aliases(stats: dict) -> dict:
-    """Rewrite deprecated keys in a stats dict to their canonical names
-    (DeprecationWarning per hit).  Canonical keys win on collision."""
-    import warnings
-    out = {}
-    for k, v in stats.items():
-        c = canonical_name(k)
-        if c != k:
-            warnings.warn(f"dispatch_stats key {k!r} is deprecated; use "
-                          f"{c!r}", DeprecationWarning, stacklevel=2)
-            out.setdefault(c, v)
-        else:
-            out[k] = v
-    return out
+    return METRICS[name]
 
 
 def schema() -> dict:
     """The machine-readable metrics schema: name -> {kind, types, unit,
-    description, aliases}."""
-    inv: Dict[str, List[str]] = {}
-    for old, new in DEPRECATED_ALIASES.items():
-        inv.setdefault(new, []).append(old)
+    description}."""
     return {
         name: {
             "kind": m.kind,
             "types": [t.__name__ for t in m.types],
             "unit": m.unit,
             "description": m.description,
-            "aliases": sorted(inv.get(name, [])),
         }
         for name, m in sorted(METRICS.items())
     }
 
 
 def validate_stats(stats: dict, *, where: str = "dispatch_stats") -> None:
-    """Every key must be a catalog name (aliases rejected: producers emit
-    canonical names) carrying a value of the registered type."""
+    """Every key must be a catalog name carrying a value of the registered
+    type."""
     for k, v in stats.items():
         if k not in METRICS:
-            hint = (f" (deprecated alias of {DEPRECATED_ALIASES[k]!r})"
-                    if k in DEPRECATED_ALIASES else "")
-            raise ValueError(f"{where}: unknown metric {k!r}{hint}")
+            raise ValueError(f"{where}: unknown metric {k!r}")
         m = METRICS[k]
         if m.types and not (isinstance(v, m.types)
                             and not (isinstance(v, bool)
@@ -287,6 +260,23 @@ def _now_us(origin: float) -> int:
     return int(round((time.perf_counter() - origin) * 1e6))
 
 
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?(%[\w.\-]+)\s*=")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def hlo_op_names(hlo_text: str) -> Dict[str, str]:
+    """{instruction: op_name metadata} of a compiled HLO module's text,
+    ``""`` for an instruction the compiler made without one.  The names
+    are those a device profile gives its op events (``%fusion.12``)."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m:
+            name = _OP_NAME.search(line)
+            out[m.group(1)] = name.group(1) if name else ""
+    return out
+
+
 class FlightRecorder:
     """A bounded ring buffer of telemetry events with a span tracer.
 
@@ -298,7 +288,6 @@ class FlightRecorder:
       recorders keep counting up from their last timestamp).
     * ``{"type": "round", "epoch", "round", "foreign_per_client", ...}``
       — one decoded in-graph exchange round (see ``record_epoch_rounds``).
-    * ``{"type": "mark", "name", "ts", ...}`` — an instant annotation.
 
     The deque drops the OLDEST events at capacity — a flight recorder
     keeps the latest window, like the real thing.
@@ -312,6 +301,10 @@ class FlightRecorder:
         self.counters: Dict[str, float] = {}
         self._origin = time.perf_counter()
         self._depth = 0
+        # under plan.profile: compiled module name -> hlo_op_names, for the
+        # programs noted by note_program (not persisted)
+        self.programs: Dict[str, Dict[str, str]] = {}
+        self._noted: set = set()
         self.wall_start = time.time()
 
     # -- spans --------------------------------------------------------------
@@ -338,10 +331,63 @@ class FlightRecorder:
             self.events.append({"type": "span", "name": name, "ts": ts,
                                 "dur": dur, "depth": depth, **attrs})
 
-    def mark(self, name: str, **attrs) -> None:
-        if self.plan.spans:
-            self.events.append({"type": "mark", "name": name,
-                                "ts": _now_us(self._origin), **attrs})
+    @contextlib.contextmanager
+    def gc_spans(self):
+        """Record every generation-2 garbage collection while open as a
+        ``gc`` span (a ``TraceAnnotation`` too under ``plan.profile``), so
+        the host pauses they cause carry their cause in a trace.  No hook
+        is registered unless the plan enables spans."""
+        if not self.plan.spans:
+            yield
+            return
+        open_ = []
+
+        def hook(phase, info):
+            if info["generation"] != 2:
+                return
+            if phase == "start":
+                ann = (jax.profiler.TraceAnnotation("gc")
+                       if self.plan.profile else contextlib.nullcontext())
+                ann.__enter__()
+                open_.append((ann, time.perf_counter(),
+                              _now_us(self._origin)))
+            elif open_:
+                ann, t0, ts = open_.pop()
+                ann.__exit__(None, None, None)
+                self.events.append({
+                    "type": "span", "name": "gc", "ts": ts,
+                    "dur": int(round((time.perf_counter() - t0) * 1e6)),
+                    "depth": self._depth, "generation": 2,
+                    "collected": info["collected"]})
+
+        gc.callbacks.append(hook)
+        try:
+            yield
+        finally:
+            gc.callbacks.remove(hook)
+
+    def note_program(self, fn, *args) -> None:
+        """Under ``plan.profile``, keep the ``op_name`` of every instruction
+        of ``fn``'s compiled program for ``args`` in ``programs``, under the
+        module's name, once per ``fn``: a device profile names bare
+        instructions, and this map puts each one in its named scope.  Call
+        it before the dispatch that donates ``args``.  Costs one lowering
+        and one compile.  The compile keys JAX's persistent cache with the
+        metadata included: under the default key, which leaves it out, a
+        hit may serve a compile of the same computation made before its
+        scopes existed, whose ``op_name``s lack them."""
+        if not self.plan.profile or fn in self._noted:
+            return
+        self._noted.add(fn)
+        opt = "jax_compilation_cache_include_metadata_in_key"
+        was = getattr(jax.config, opt)
+        jax.config.update(opt, True)
+        try:
+            text = fn.lower(*args).compile().as_text()
+        finally:
+            jax.config.update(opt, was)
+        module = text.split(",", 1)[0].split()[-1]   # "HloModule <name>, ..."
+        self.programs[module] = hlo_op_names(text)
 
     # -- counters -----------------------------------------------------------
 
@@ -448,4 +494,15 @@ def span(recorder: Optional[FlightRecorder], name: str, **attrs):
         yield
     else:
         with recorder.span(name, **attrs):
+            yield
+
+
+@contextlib.contextmanager
+def gc_spans(recorder: Optional[FlightRecorder]):
+    """``with gc_spans(rec): ...`` — :meth:`FlightRecorder.gc_spans`, or a
+    no-op (no hook registered) when ``rec`` is None."""
+    if recorder is None:
+        yield
+    else:
+        with recorder.gc_spans():
             yield

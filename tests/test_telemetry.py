@@ -10,6 +10,7 @@ log at exchange cadences k in {1, 2}; the flight recorder's ring buffer
 is bounded; the JSONL -> Chrome-trace/Perfetto export is pinned by
 golden files; and a checkpointed recorder restores bit-identically and
 keeps its monotonic clock counting upward."""
+import gc
 import json
 import os
 import subprocess
@@ -22,7 +23,7 @@ import pytest
 
 from repro.core import telemetry as TEL
 from repro.core.experiment import tensor_population
-from repro.core.federation import Federation, RoundSchedule
+from repro.core.federation import Callback, Federation, RoundSchedule
 from repro.core.hfl import HFLConfig
 from repro.core.policies import policy_from_spec
 
@@ -90,38 +91,21 @@ def test_federation_rejects_non_plan():
 # MetricsRegistry
 # ---------------------------------------------------------------------------
 
-def test_metric_aliases_resolve_with_warning():
-    assert TEL.canonical_name("bytes_gathered") == "pool_bytes_gathered"
-    assert TEL.canonical_name("rejected_heads") == "heads_rejected"
-    assert TEL.canonical_name("heads_rejected") == "heads_rejected"
-    with pytest.warns(DeprecationWarning, match="bytes_gathered"):
-        out = TEL.resolve_aliases({"bytes_gathered": 7, "devices": 1})
-    assert out == {"pool_bytes_gathered": 7, "devices": 1}
-    # canonical keys win on collision with their own deprecated alias
-    with pytest.warns(DeprecationWarning):
-        out = TEL.resolve_aliases({"heads_rejected": 3,
-                                   "rejected_heads": 9})
-    assert out["heads_rejected"] == 3
-
-
 def test_metrics_schema_is_json_clean_and_self_describing():
     sch = TEL.schema()
     assert json.loads(json.dumps(sch)) == sch
     for name, m in sch.items():
         assert m["kind"] in TEL.KINDS, name
         assert m["description"], name
-    # every deprecated alias points at a catalog entry and is listed back
-    for old, new in TEL.DEPRECATED_ALIASES.items():
-        assert new in sch
-        assert old in sch[new]["aliases"]
+        assert TEL.metric_spec(name).unit == m["unit"]
 
 
 def test_validate_stats_rejects_unknown_and_aliased_keys():
     TEL.validate_stats({"heads_rejected": 2, "devices": 1})
     with pytest.raises(ValueError, match="made_up_metric"):
         TEL.validate_stats({"made_up_metric": 1})
-    with pytest.raises(ValueError, match="deprecated alias"):
-        TEL.validate_stats({"rejected_heads": 2})
+    with pytest.raises(ValueError, match="rejected_heads"):
+        TEL.validate_stats({"rejected_heads": 2})   # not a catalog name
     with pytest.raises(ValueError, match="heads_rejected"):
         TEL.validate_stats({"heads_rejected": 2.5})
 
@@ -229,7 +213,8 @@ def test_round_series_sentinels_when_not_federating():
 def test_ring_buffer_bounded_keeps_newest():
     rec = TEL.FlightRecorder(TEL.TelemetryPlan(ring_size=8))
     for i in range(100):
-        rec.mark(f"m{i}")
+        with rec.span(f"m{i}"):
+            pass
     assert len(rec.events) == 8
     assert [e["name"] for e in rec.events] == [f"m{i}"
                                                for i in range(92, 100)]
@@ -250,7 +235,8 @@ def test_span_nesting_depth_and_counters():
 def test_disabled_spans_record_nothing():
     rec = TEL.FlightRecorder(TEL.TelemetryPlan(spans=False))
     with rec.span("fit"):
-        rec.mark("m")
+        with rec.span("dispatch"):
+            pass
     assert not rec.events
     with TEL.span(None, "anything"):      # module-level no-op form
         pass
@@ -299,7 +285,9 @@ def test_live_run_exports_valid_trace(tmp_path):
     validate_trace(trace)
     assert_spans_nest(trace["traceEvents"])
     names = {e["name"] for e in trace["traceEvents"] if e["ph"] == "X"}
-    assert {"fit", "dispatch", "exchange"} <= names
+    assert {"fit", "restack", "dispatch", "readback", "record",
+            "writeback", "results", "test_pass"} <= names
+    assert "exchange" not in names    # the orchestrator's wave span only
     assert any(e["ph"] == "C" for e in trace["traceEvents"])
 
 
@@ -336,6 +324,224 @@ def test_assert_spans_nest_rejects_partial_overlap():
                  "pid": 1, "tid": 1}]
     with pytest.raises(ValueError, match="partially overlaps"):
         assert_spans_nest(bad)
+
+
+# ---------------------------------------------------------------------------
+# Layer timing: named scopes in the fused epoch, spans in the fit loop
+# ---------------------------------------------------------------------------
+
+EPOCH_SCOPES = ("policy_round", "eq7_score", "train_step", "eval_best")
+
+
+def _spy_epoch_fn(monkeypatch, module, factory):
+    """Wrap ``module.factory`` so the first epoch dispatch leaves its
+    compiled function and argument shapes behind."""
+    import jax
+    real = getattr(module, factory)
+    seen = {}
+
+    def spy(*a, **kw):
+        fn = real(*a, **kw)
+
+        def call(*args):
+            seen.setdefault("fn", fn)
+            seen.setdefault("args", jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args))
+            return fn(*args)
+        call.lower = fn.lower
+        return call
+
+    monkeypatch.setattr(module, factory, spy)
+    return seen
+
+
+@pytest.mark.parametrize("nf_choices,kernel", [
+    ((3,), False), ((3,), True), ((3, 4), False), ((3, 4), True)],
+    ids=("batched", "batched-kernel", "cohort", "cohort-kernel"))
+def test_epoch_scopes_in_op_metadata(monkeypatch, nf_choices, kernel):
+    """Every layer scope names ops of the lowered and of the compiled
+    epoch program, on the vmap scorer and on the Pallas pool kernel; under
+    ``profile`` the recorder keeps the compiled program's op names."""
+    from repro.core import cohorts, federation
+    module, factory = ((federation, "_make_epoch_fn") if len(nf_choices) == 1
+                       else (cohorts, "_make_hetero_epoch_fn"))
+    seen = _spy_epoch_fn(monkeypatch, module, factory)
+    fed, _ = _fit(_cfg(epochs=1, use_pool_kernel=kernel), n=4,
+                  nf_choices=nf_choices,
+                  telemetry=TEL.TelemetryPlan(rounds=False, profile=True))
+    lowered = seen["fn"].lower(*seen["args"]).as_text(dialect="hlo",
+                                                      debug_info=True)
+    compiled = fed._recorder.programs["jit_epoch"]
+    assert compiled == TEL.hlo_op_names(
+        seen["fn"].lower(*seen["args"]).compile().as_text())
+    for names in ([line.split('op_name="', 1)[1].split('"', 1)[0]
+                   for line in lowered.splitlines() if 'op_name="' in line],
+                  compiled.values()):
+        paths = [n.split("/") for n in names]
+        for scope in EPOCH_SCOPES:
+            assert any(scope in p for p in paths), scope
+    # compiled op names carry the whole path (lowered ones start afresh in
+    # each called function): the scorer runs inside the policy round
+    assert all("policy_round" in p for p in paths if "eq7_score" in p)
+    assert all(k.startswith("%") for k in compiled)
+
+
+def test_hlo_op_names():
+    text = """HloModule jit_epoch, is_scheduled=true
+
+%fused (p.2: f32[4]) -> f32[4] {
+  %p.2 = f32[4]{0} parameter(0)
+  ROOT %tanh.2 = f32[4]{0} tanh(%p.2), metadata={op_name="jit(epoch)/policy_round/eq7_score/tanh" stack_frame_id=5}
+}
+
+ENTRY %main (p: f32[4]) -> f32[4] {
+  %p = f32[4]{0} parameter(0)
+  %fusion.1 = f32[4]{0} fusion(%p), kind=kLoop, calls=%fused, metadata={op_name="jit(epoch)/policy_round/eq7_score/tanh"}
+  ROOT %copy-done.3 = f32[4]{0} copy-done(%fusion.1)
+}
+"""
+    assert TEL.hlo_op_names(text) == {
+        "%p.2": "", "%tanh.2": "jit(epoch)/policy_round/eq7_score/tanh",
+        "%p": "", "%fusion.1": "jit(epoch)/policy_round/eq7_score/tanh",
+        "%copy-done.3": ""}
+
+
+_STALE_CACHE = r"""
+import sys
+import jax
+import jax.numpy as jnp
+from repro.core import telemetry as TEL
+
+jax.config.update("jax_compilation_cache_dir", sys.argv[1])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
+def make(scope):
+    def f(x):
+        with jax.named_scope(scope):
+            return jnp.tanh(x @ x)
+    return jax.jit(f)
+
+
+x = jnp.ones((8, 8))
+if sys.argv[2] == "before":
+    make("before")(x).block_until_ready()     # fills the persistent cache
+else:
+    rec = TEL.FlightRecorder(TEL.TelemetryPlan(profile=True))
+    g = make("policy_round")
+    rec.note_program(g, x)
+    g(x).block_until_ready()
+    print("RESULT " + " ".join(v for v in rec.programs["jit_f"].values()
+                               if v))
+"""
+
+
+def test_note_program_sees_scopes_past_a_stale_cache_entry(tmp_path):
+    """JAX's persistent cache keys a program without its metadata, so a
+    cache filled before a scope existed serves the scope-less op names;
+    ``note_program`` keys its compile with the metadata and sees the
+    scope."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = str(ROOT / "src") + os.pathsep \
+        + env.get("PYTHONPATH", "")
+    outs = [subprocess.run([sys.executable, "-c", _STALE_CACHE,
+                            str(tmp_path), step], env=env,
+                           capture_output=True, text=True, timeout=300)
+            for step in ("before", "after")]
+    assert all(o.returncode == 0 for o in outs), outs[-1].stderr[-2000:]
+    line = [ln for ln in outs[1].stdout.splitlines()
+            if ln.startswith("RESULT ")]
+    names = line[-1].split()[1:]
+    assert any("/policy_round/" in n for n in names), names
+    assert not any("/before/" in n for n in names), names
+
+
+def _spans(rec):
+    return [e for e in rec.events if e["type"] == "span"]
+
+
+def _inside(inner, outer):
+    return (outer["ts"] <= inner["ts"]
+            and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1)
+
+
+@pytest.mark.parametrize("nf_choices", ((3,), (3, 4)),
+                         ids=("batched", "cohort"))
+def test_fit_driver_spans(nf_choices):
+    """Both engines record the same fit-driver spans: ``restack``,
+    ``dispatch``, ``readback`` and ``record`` per epoch and ``writeback``
+    inside ``fit``; ``results`` after it with ``test_pass`` inside."""
+    fed, _ = _fit(_cfg(epochs=2), nf_choices=nf_choices,
+                  telemetry=TEL.TelemetryPlan(rounds=False))
+    spans = _spans(fed._recorder)
+    by = {}
+    for e in spans:
+        by.setdefault(e["name"], []).append(e)
+    assert set(by) >= {"fit", "restack", "dispatch", "readback", "record",
+                       "writeback", "results", "test_pass"}
+    assert "exchange" not in by
+    for name in ("dispatch", "readback", "record"):
+        assert [e["epoch"] for e in by[name]] == [0, 1], name
+    (fit,), (results,), (test_pass,) = by["fit"], by["results"], \
+        by["test_pass"]
+    for name in ("restack", "writeback", "dispatch", "readback", "record"):
+        assert all(_inside(e, fit) and e["depth"] == fit["depth"] + 1
+                   for e in by[name]), name
+    assert _inside(test_pass, results)
+    assert test_pass["depth"] == results["depth"] + 1
+    assert results["ts"] >= fit["ts"] + fit["dur"] - 1
+    assert fed._recorder.programs == {}     # kept under `profile` only
+
+
+class _CollectOnce(Callback):
+    """An ``on_epoch_end`` callback that forces one generation-2
+    collection and notes how many gc hooks are registered meanwhile."""
+
+    def __init__(self):
+        self.hooks = []
+
+    def on_epoch_end(self, fed, epoch, val, active):
+        self.hooks.append(len(gc.callbacks))
+        if epoch == 0:
+            gc.collect(2)
+
+
+def _fit_collecting(telemetry):
+    cfg = _cfg(epochs=2)
+    cb = _CollectOnce()
+    fed = Federation(_pop(cfg, 4).build(range(4)), cfg, engine="batched",
+                     callbacks=[cb], telemetry=telemetry)
+    before = len(gc.callbacks)
+    was = gc.isenabled()
+    gc.disable()            # no collection but the forced one
+    try:
+        fed.fit()
+    finally:
+        if was:
+            gc.enable()
+    assert len(gc.callbacks) == before     # nothing left registered
+    return fed, cb.hooks, before
+
+
+def test_gc_collection_recorded_as_span():
+    fed, hooks, before = _fit_collecting(TEL.TelemetryPlan(rounds=False))
+    assert hooks == [before + 1] * 2       # registered for the fit only
+    spans = _spans(fed._recorder)
+    gcs = [e for e in spans if e["name"] == "gc"]
+    assert len(gcs) == 1 and gcs[0]["generation"] == 2
+    (fit,) = [e for e in spans if e["name"] == "fit"]
+    assert _inside(gcs[0], fit)
+
+
+@pytest.mark.parametrize("telemetry", (
+    None, TEL.TelemetryPlan(rounds=True, spans=False)),
+    ids=("none", "spans-off"))
+def test_no_gc_hook_without_spans(telemetry):
+    fed, hooks, before = _fit_collecting(telemetry)
+    assert hooks == [before] * 2
+    rec = fed._recorder
+    assert rec is None or not _spans(rec)
 
 
 # ---------------------------------------------------------------------------
