@@ -58,10 +58,11 @@ import numpy as np
 from repro.core import mesh_federation as MF
 from repro.core import telemetry as TEL
 from repro.core import trust as TR
-from repro.core.federation import (_exchange_round_bytes,
-                                   _hold_client_copies_on_host,
-                                   _policy_round_body, _stack_trees,
-                                   _tree_bytes, _tree_row, _wants_per_round)
+from repro.core.federation import (_count_restack, _exchange_round_bytes,
+                                   _hold_client_copies_on_host, _is_host,
+                                   _policy_round_body, _stack_data,
+                                   _stack_trees, _to_host, _tree_bytes,
+                                   _tree_row, _wants_per_round)
 from repro.core.hfl import FederatedClient, _eval_mse, _train_step
 from repro.core.policies import FederationPolicies
 from repro.optim import adam
@@ -181,11 +182,12 @@ def pad_features(tree, max_nf: int):
     head tree to ``max_nf`` — the padded rows are dead weight the validity
     masks hide from every selection."""
     def pad(p):
-        p = jnp.asarray(p)
+        xp = np if _is_host(p) else jnp
+        p = xp.asarray(p)
         if p.shape[0] == max_nf:
             return p
-        return jnp.concatenate(
-            [p, jnp.zeros((max_nf - p.shape[0],) + p.shape[1:], p.dtype)], 0)
+        return xp.concatenate(
+            [p, xp.zeros((max_nf - p.shape[0],) + p.shape[1:], p.dtype)], 0)
     return jax.tree_util.tree_map(pad, tree)
 
 
@@ -682,12 +684,12 @@ def _fit_cohorted(fed, n_epochs: int, cbs) -> None:
         padded rounds are masked no-ops)."""
         Ck = t.shape[0]
         m = n_sub * R
-        r = jnp.moveaxis(t[:, :m].reshape((Ck, n_sub, R) + t.shape[2:]),
-                         1, 0)
+        r = np.moveaxis(t[:, :m].reshape((Ck, n_sub, R) + t.shape[2:]),
+                        1, 0)
         if n_sub < n_sub_max:
-            r = jnp.concatenate(
-                [r, jnp.zeros((n_sub_max - n_sub,) + r.shape[1:],
-                              r.dtype)], 0)
+            r = np.concatenate(
+                [r, np.zeros((n_sub_max - n_sub,) + r.shape[1:],
+                             r.dtype)], 0)
         return r
 
     # telemetry layer: `tele` = the enabled plan iff the in-graph series is
@@ -695,19 +697,21 @@ def _fit_cohorted(fed, n_epochs: int, cbs) -> None:
     # the host-side flight recorder
     tele = fed._tele_rounds()
     rec = fed._recorder
+    mesh = fed._exec_mesh()
+    key = fed._key
     with TEL.span(rec, "restack"):
         rounds_t, val_t = [], []
         params_l, opt_l, bv_l, bp_l = [], [], [], []
         for co in plan.cohorts:
             cs = [clients[i] for i in co.members]
             rounds_t.append(tuple(
-                rounds_axis(jnp.stack([np.asarray(c.train[j]) for c in cs]),
-                            co.n_sub) for j in range(3)))
-            val_t.append(tuple(jnp.stack([np.asarray(c.valid[j])
-                                          for c in cs]) for j in range(3)))
+                rounds_axis(_stack_data([c.train[j] for c in cs]), co.n_sub)
+                for j in range(3)))
+            val_t.append(tuple(_stack_data([c.valid[j] for c in cs])
+                               for j in range(3)))
             params_l.append(_stack_trees([c.params for c in cs]))
             opt_l.append(_stack_trees([c.opt_state for c in cs]))
-            bv_l.append(jnp.asarray([c.best_val for c in cs], jnp.float32))
+            bv_l.append(np.asarray([c.best_val for c in cs], np.float32))
             bp_l.append(_stack_trees([c.best_params for c in cs]))
         rounds_t, val_t = tuple(rounds_t), tuple(val_t)
         params_t, opt_t = tuple(params_l), tuple(opt_l)
@@ -715,8 +719,23 @@ def _fit_cohorted(fed, n_epochs: int, cbs) -> None:
         del params_l, opt_l, bv_l, bp_l
         pool_heads = stack_hetero_pool(fed.pool, names, plan.nfs,
                                        plan.max_nf)
-        pool_age = jnp.asarray([fed.pool.age_of(n_) for n_ in names],
-                               jnp.int32)
+        pool_age = np.asarray([fed.pool.age_of(n_) for n_ in names],
+                              np.int32)
+        _count_restack(rec, params_t, opt_t, best_params_t, pool_heads)
+        # one placement per fit, straight from the host on a mesh
+        if mesh is not None:
+            (params_t, opt_t, pool_heads, pool_age, key, best_val_t,
+             best_params_t, rounds_t, val_t) = shard_hetero_fit_state(
+                mesh, plan, cfg.w, params_t=params_t, opt_t=opt_t,
+                pool_heads=pool_heads, pool_age=pool_age, key=key,
+                best_val_t=best_val_t, best_params_t=best_params_t,
+                rounds_t=rounds_t, val_t=val_t)
+            _hold_client_copies_on_host(fed)
+        else:
+            (params_t, opt_t, pool_heads, pool_age, best_val_t,
+             best_params_t, rounds_t, val_t) = jax.device_put(
+                (params_t, opt_t, pool_heads, pool_age, best_val_t,
+                 best_params_t, rounds_t, val_t))
     use_kernel = cfg.use_pool_kernel
     lut = hetero_selection_lut(names, plan.nfs, plan.max_nf)
     admission = fed._admission()
@@ -764,17 +783,6 @@ def _fit_cohorted(fed, n_epochs: int, cbs) -> None:
                       zip(params_t, opt_t, best_params_t))
     n_rounds = np.zeros(C, np.int64)
     base_rounds = dict(fed.n_rounds)
-    key = fed._key
-
-    mesh = fed._exec_mesh()
-    if mesh is not None:
-        (params_t, opt_t, pool_heads, pool_age, key, best_val_t,
-         best_params_t, rounds_t, val_t) = shard_hetero_fit_state(
-            mesh, plan, cfg.w, params_t=params_t, opt_t=opt_t,
-            pool_heads=pool_heads, pool_age=pool_age, key=key,
-            best_val_t=best_val_t, best_params_t=best_params_t,
-            rounds_t=rounds_t, val_t=val_t)
-        _hold_client_copies_on_host(fed)
 
     def make_epoch_fn(do_federate: bool, do_eval: bool,
                       exchange_every: int = 1):
@@ -843,19 +851,20 @@ def _fit_cohorted(fed, n_epochs: int, cbs) -> None:
         """Write the per-cohort loop state back into the clients / pool /
         rng — after the loop, and on demand for mid-fit checkpoints."""
         with TEL.span(rec, "writeback"):
-            ages = np.asarray(pool_age)
+            params_h, opt_h, best_h, bv_h, heads_h, ages = _to_host(
+                (params_t, opt_t, best_params_t, best_val_t, pool_heads,
+                 pool_age))
             for k, co in enumerate(plan.cohorts):
-                bv = np.asarray(best_val_t[k])
                 for r, i in enumerate(co.members):
                     c = clients[i]
-                    c.params = _tree_row(params_t[k], r)
-                    c.opt_state = _tree_row(opt_t[k], r)
+                    c.params = _tree_row(params_h[k], r)
+                    c.opt_state = _tree_row(opt_h[k], r)
                     c.val_history = histories[i]
-                    c.best_val = float(bv[r])
-                    c.best_params = _tree_row(best_params_t[k], r)
+                    c.best_val = float(bv_h[k][r])
+                    c.best_params = _tree_row(best_h[k], r)
             for i, c in enumerate(clients):
                 row = jax.tree_util.tree_map(
-                    lambda p: p[i, :plan.nfs[i]], pool_heads)
+                    lambda p: p[i, :plan.nfs[i]], heads_h)
                 fed.pool.publish(c.name, row, plan.nfs[i], age=int(ages[i]))
                 fed.n_rounds[c.name] = (base_rounds[c.name]
                                         + int(n_rounds[i]))

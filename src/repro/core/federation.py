@@ -881,10 +881,50 @@ def fused_policy_round(heads, pool_heads, pool_age, xd_R, y_R, active, key,
                               use_kernel=use_kernel)
 
 
+def _is_host(x) -> bool:
+    return isinstance(x, (np.ndarray, np.generic))
+
+
+def _stack_leaf(*xs):
+    """np.stack when every row is a host array (a byte copy, placed on the
+    device later in one transfer per fit), else jnp.stack: stacking host
+    rows with jnp.stack would send each row to the device on its own."""
+    return np.stack(xs) if all(map(_is_host, xs)) else jnp.stack(xs)
+
+
 def _stack_trees(trees):
     """Stack a list of same-structure pytrees leaf-wise on a new leading
-    axis — the batched engine's (C, ...) client stacking."""
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
+    axis — the batched engine's (C, ...) client stacking, per leaf on the
+    host or on the device as :func:`_stack_leaf` picks."""
+    return jax.tree_util.tree_map(_stack_leaf, *trees)
+
+
+def _stack_data(arrays) -> np.ndarray:
+    """Stack the clients' data arrays of one split on the host."""
+    return np.stack([np.asarray(a) for a in arrays])
+
+
+def _count_restack(rec, *trees) -> None:
+    """Count the stacked state's leaves by where they were stacked: their
+    ratio is the share of restacks that took the host path."""
+    if rec is None:
+        return
+    leaves = jax.tree_util.tree_leaves(trees)
+    n_host = sum(map(_is_host, leaves))
+    for name, n in (("restack_host_leaves", n_host),
+                    ("restack_device_leaves", len(leaves) - n_host)):
+        if n:
+            rec.count(name, n)
+
+
+def _to_host(tree):
+    """One device->host copy of a stacked tree, its leaves made read-only:
+    the client rows handed out as views of it stay as immutable as the
+    device arrays they replace."""
+    host = jax.device_get(tree)
+    for leaf in jax.tree_util.tree_leaves(host):
+        leaf.setflags(write=False)
+    return host
 
 
 def _tree_bytes(tree) -> int:
@@ -932,8 +972,9 @@ def _hold_client_copies_on_host(fed) -> None:
 
 
 def _tree_row(tree, i):
-    """Client i's slice of a stacked (C, ...) tree."""
-    return jax.tree_util.tree_map(lambda p: p[i], tree)
+    """Client i's slice of a stacked (C, ...) tree (of a host tree: array
+    views, 0-d for a (C,) leaf)."""
+    return jax.tree_util.tree_map(lambda p: p[i, ...], tree)
 
 
 def _selection_lut(names: Sequence[str], nf: int) -> np.ndarray:
@@ -1286,14 +1327,20 @@ def _fit_batched(fed: "Federation", n_epochs: int, cbs) -> None:
         on a leading scan axis (the slices are contiguous from 0, so this is
         a reshape + transpose, done once per fit)."""
         m = n_sub * R
-        return jnp.moveaxis(
+        return np.moveaxis(
             t[:, :m].reshape((C, n_sub, R) + t.shape[2:]), 1, 0)
 
+    # client-sharded execution: with a multi-device mesh the stacked state
+    # is partitioned over the `clients` axis once per fit (subsequent
+    # epochs carry the shardings through the donated outputs) and the
+    # epoch function is the shard_map twin of _make_epoch_fn
+    mesh = fed._exec_mesh()
+    key = fed._key
     with TEL.span(rec, "restack"):
         xs_r, xd_r, y_r = (
-            rounds_axis(jnp.stack([np.asarray(c.train[k]) for c in clients]))
+            rounds_axis(_stack_data([c.train[k] for c in clients]))
             for k in range(3))
-        val = tuple(jnp.stack([np.asarray(c.valid[k]) for c in clients])
+        val = tuple(_stack_data([c.valid[k] for c in clients])
                     for k in range(3))
         params = _stack_trees([c.params for c in clients])
         opt_state = _stack_trees([c.opt_state for c in clients])
@@ -1301,10 +1348,25 @@ def _fit_batched(fed: "Federation", n_epochs: int, cbs) -> None:
         # the initial publication; a restored fit sees the checkpointed
         # pool)
         pool_heads = stack_pool(fed.pool, names, nf)
-        pool_age = jnp.asarray([fed.pool.age_of(n_) for n_ in names],
-                               jnp.int32)
-        best_val = jnp.asarray([c.best_val for c in clients], jnp.float32)
+        pool_age = np.asarray([fed.pool.age_of(n_) for n_ in names],
+                              np.int32)
+        best_val = np.asarray([c.best_val for c in clients], np.float32)
         best_params = _stack_trees([c.best_params for c in clients])
+        _count_restack(rec, params, opt_state, best_params, pool_heads)
+        # one placement per fit, straight from the host on a mesh
+        if mesh is not None:
+            (params, opt_state, pool_heads, pool_age, key, best_val,
+             best_params, (xs_r, xd_r, y_r), val) = MF.shard_fit_state(
+                mesh, nf, cfg.w, C, params=params, opt_state=opt_state,
+                pool_heads=pool_heads, pool_age=pool_age, key=key,
+                best_val=best_val, best_params=best_params,
+                rounds_data=(xs_r, xd_r, y_r), val_data=val)
+            _hold_client_copies_on_host(fed)
+        else:
+            (params, opt_state, pool_heads, pool_age, best_val,
+             best_params, (xs_r, xd_r, y_r), val) = jax.device_put(
+                (params, opt_state, pool_heads, pool_age, best_val,
+                 best_params, (xs_r, xd_r, y_r), val))
     use_kernel = cfg.use_pool_kernel
     lut = _selection_lut(names, nf)
     admission = fed._admission()
@@ -1343,21 +1405,6 @@ def _fit_batched(fed: "Federation", n_epochs: int, cbs) -> None:
                    + _tree_bytes(best_params))
     n_rounds = np.zeros(C, np.int64)
     base_rounds = dict(fed.n_rounds)
-    key = fed._key
-
-    # client-sharded execution: with a multi-device mesh the stacked state
-    # is partitioned over the `clients` axis once per fit (subsequent
-    # epochs carry the shardings through the donated outputs) and the
-    # epoch function is the shard_map twin of _make_epoch_fn
-    mesh = fed._exec_mesh()
-    if mesh is not None:
-        (params, opt_state, pool_heads, pool_age, key, best_val,
-         best_params, (xs_r, xd_r, y_r), val) = MF.shard_fit_state(
-            mesh, nf, cfg.w, C, params=params, opt_state=opt_state,
-            pool_heads=pool_heads, pool_age=pool_age, key=key,
-            best_val=best_val, best_params=best_params,
-            rounds_data=(xs_r, xd_r, y_r), val_data=val)
-        _hold_client_copies_on_host(fed)
 
     def make_epoch_fn(do_federate: bool, do_eval: bool,
                       exchange_every: int = 1):
@@ -1423,15 +1470,16 @@ def _fit_batched(fed: "Federation", n_epochs: int, cbs) -> None:
         run after the loop, and on demand when a callback checkpoints the
         federation mid-fit (Federation.save calls this hook)."""
         with TEL.span(rec, "writeback"):
-            ages = np.asarray(pool_age)
-            bv = np.asarray(best_val)
+            params_h, opt_h, best_h, heads_h, ages, bv = _to_host(
+                (params, opt_state, best_params, pool_heads, pool_age,
+                 best_val))
             for i, c in enumerate(clients):
-                c.params = _tree_row(params, i)
-                c.opt_state = _tree_row(opt_state, i)
+                c.params = _tree_row(params_h, i)
+                c.opt_state = _tree_row(opt_h, i)
                 c.val_history = histories[i]
                 c.best_val = float(bv[i])
-                c.best_params = _tree_row(best_params, i)
-                fed.pool.publish(c.name, _tree_row(pool_heads, i), nf,
+                c.best_params = _tree_row(best_h, i)
+                fed.pool.publish(c.name, _tree_row(heads_h, i), nf,
                                  age=int(ages[i]))
                 fed.n_rounds[c.name] = (base_rounds[c.name]
                                         + int(n_rounds[i]))
@@ -1908,10 +1956,11 @@ class Federation:
                 if len(cl) == 1:
                     out[cl[0].name] = cl[0].test_mse()
                     continue
-                tst = tuple(jnp.stack([np.asarray(c.test[k]) for c in cl])
+                tst = tuple(_stack_data([c.test[k] for c in cl])
                             for k in range(3))
                 bp = _stack_trees([c.best_params for c in cl])
-                v = np.asarray(eval_fn(bp, *tst), np.float64)
+                v = np.asarray(eval_fn(*jax.device_put((bp, *tst))),
+                               np.float64)
                 out.update({c.name: float(v[i]) for i, c in enumerate(cl)})
             return out
         return {c.name: c.test_mse() for c in self.clients}

@@ -71,9 +71,11 @@ from repro.core.policies import (FederationPolicies, _Spec, policy_from_spec,
 
 def host_tree(tree):
     """A bit-exact host copy of a pytree: every leaf as a numpy array.
-    ``np.asarray`` on a device array is a dtype-preserving byte copy, so a
-    store round-trip (device → store → device) is exact."""
-    return jax.tree_util.tree_map(np.asarray, tree)
+    ``np.array`` is a dtype-preserving byte copy, so a store round-trip
+    (device → store → device) is exact, and an entry owns its bytes: a
+    batched fit hands clients row views of its whole stacked state, which
+    a stored view would keep alive."""
+    return jax.tree_util.tree_map(np.array, tree)
 
 
 # ---------------------------------------------------------------------------

@@ -25,7 +25,9 @@ Three pieces, all optional and all zero-cost when absent:
   trace annotations behind ``plan.profile``, the decoded per-round metric
   records, and a counter registry snapshot.  The batched fit driver (both
   engines) opens ``fit``; inside it ``restack`` (stacking the population,
-  pool and best parameters), per epoch ``dispatch`` (the enqueue of the
+  pool and best parameters and placing them on the device; the counters
+  ``restack_host_leaves`` / ``restack_device_leaves`` say where each
+  stacked leaf was stacked), per epoch ``dispatch`` (the enqueue of the
   fused epoch), ``readback`` (the blocking read of its validation MSEs
   and choices) and ``record`` (selections, histories, round series), and
   ``writeback`` (``sync()``); after it ``results`` with ``test_pass``
@@ -212,6 +214,11 @@ METRICS: Dict[str, MetricSpec] = {m.name: m for m in [
        "head selections recorded in round events"),
     _m("client_rounds", "counter", (int,), "rounds", "client exchange "
        "rounds executed (throughput numerator)"),
+    _m("restack_host_leaves", "counter", (int,), "leaves", "stacked-state "
+       "leaves a batched fit stacked on the host (np.stack of host rows)"),
+    _m("restack_device_leaves", "counter", (int,), "leaves", "stacked-state "
+       "leaves a batched fit stacked on the device (some row a device "
+       "array)"),
     _m("score_min", "histogram", _NUM, "", "per-round minimum Eq.-7 score "
        "over valid candidates"),
     _m("score_mean", "histogram", _NUM, "", "per-round mean Eq.-7 score "

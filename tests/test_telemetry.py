@@ -544,6 +544,31 @@ def test_no_gc_hook_without_spans(telemetry):
     assert rec is None or not _spans(rec)
 
 
+def test_restack_counters_split_host_and_device_leaves():
+    """A fresh Federation's clients hold device arrays from init, so its
+    first fit stacks on the device; write-back leaves host rows, so the
+    second fit stacks every leaf of the stacked state on the host."""
+    import jax
+    cfg = _cfg(epochs=2)
+    clients = _pop(cfg).build(range(6))
+    fed = Federation(clients, cfg, engine="batched",
+                     schedule=RoundSchedule(2, cfg.R),
+                     telemetry=TEL.TelemetryPlan(rounds=False))
+    c0 = clients[0]
+    n_leaves = len(jax.tree_util.tree_leaves(
+        (c0.params, c0.opt_state, c0.best_params, c0.params["heads"])))
+    names = ("restack_host_leaves", "restack_device_leaves")
+    fed.fit(epochs=1)
+    first = {k: fed._recorder.counters.get(k, 0) for k in names}
+    assert first == {"restack_host_leaves": 0,
+                     "restack_device_leaves": n_leaves}
+    fed.fit(epochs=1)
+    second = {k: fed._recorder.counters.get(k, 0) - first[k] for k in names}
+    assert second == {"restack_host_leaves": n_leaves,
+                      "restack_device_leaves": 0}
+    assert set(names) <= set(TEL.schema())
+
+
 # ---------------------------------------------------------------------------
 # Checkpoint: the recorder rides the manifest and continues the trace
 # ---------------------------------------------------------------------------
