@@ -298,7 +298,13 @@ def _hetero_epoch_body(lr: float, plan: CohortPlan,
 
     The named scopes are those of ``federation._epoch_body``:
     ``train_step`` (every cohort's step), ``policy_round`` (``eq7_score``
-    inside) and ``eval_best``."""
+    inside) and ``eval_best``, plus ``union_view``: the scatter of every
+    cohort's heads and probe batches into the padded union (with the
+    mesh's per-cohort all-gathers) and the projection of the blended
+    heads back to each cohort's native nf.  The function is named
+    ``hetero_epoch``, so its programs, single-device and mesh, compile as
+    the module ``jit_hetero_epoch``, apart from the homogeneous
+    ``jit_epoch``."""
     opt = adam(lr)
     step = jax.vmap(functools.partial(_train_step, opt))
     evaluate = jax.vmap(_eval_mse)
@@ -316,9 +322,9 @@ def _hetero_epoch_body(lr: float, plan: CohortPlan,
     if local_rows is None:
         local_rows = lambda t, k: t
 
-    def epoch(params_t, opt_t, pool_heads, pool_age, key, best_val_t,
-              best_params_t, xs_t, xd_t, y_t, part, tick, live,
-              val_xs_t, val_xd_t, val_y_t, trust_arrays=None):
+    def hetero_epoch(params_t, opt_t, pool_heads, pool_age, key, best_val_t,
+                     best_params_t, xs_t, xd_t, y_t, part, tick, live,
+                     val_xs_t, val_xd_t, val_y_t, trust_arrays=None):
 
         def train(params_t, opt_t, bx, bd, by, live_r):
             """Every cohort's masked native-geometry step for one
@@ -350,23 +356,25 @@ def _hetero_epoch_body(lr: float, plan: CohortPlan,
                 # cohort's (gathered) heads and probe batches into
                 # (C, max_nf, ...) / (C, R, max_nf, w) zero-initialized
                 # stacks — exact copies, so oracle bit-parity survives
-                heads_g = jax.tree_util.tree_map(jnp.zeros_like, pool_heads)
-                w = bd[0].shape[-1]
-                xd_g = jnp.zeros((C, R, max_nf, w), bd[0].dtype)
-                y_g = jnp.zeros((C, R), by[0].dtype)
-                for k in range(K):
-                    idx = members[k]
-                    hk = _pad_axis1(gather(params_t[k]["heads"]), max_nf)
-                    heads_g = jax.tree_util.tree_map(
-                        lambda g, h: g.at[idx].set(h), heads_g, hk)
-                    if not secure:      # secure needs no probe scatters
-                        dk = gather(bd[k])             # (C_k, R, nf_k, w)
-                        pad = max_nf - dk.shape[2]
-                        if pad:
-                            dk = jnp.pad(dk,
-                                         ((0, 0), (0, 0), (0, pad), (0, 0)))
-                        xd_g = xd_g.at[idx].set(dk)
-                        y_g = y_g.at[idx].set(gather(by[k]))
+                with jax.named_scope("union_view"):
+                    heads_g = jax.tree_util.tree_map(jnp.zeros_like,
+                                                     pool_heads)
+                    w = bd[0].shape[-1]
+                    xd_g = jnp.zeros((C, R, max_nf, w), bd[0].dtype)
+                    y_g = jnp.zeros((C, R), by[0].dtype)
+                    for k in range(K):
+                        idx = members[k]
+                        hk = _pad_axis1(gather(params_t[k]["heads"]), max_nf)
+                        heads_g = jax.tree_util.tree_map(
+                            lambda g, h: g.at[idx].set(h), heads_g, hk)
+                        if not secure:  # secure needs no probe scatters
+                            dk = gather(bd[k])         # (C_k, R, nf_k, w)
+                            pad = max_nf - dk.shape[2]
+                            if pad:
+                                dk = jnp.pad(dk, ((0, 0), (0, 0), (0, pad),
+                                                  (0, 0)))
+                            xd_g = xd_g.at[idx].set(dk)
+                            y_g = y_g.at[idx].set(gather(by[k]))
                 if secure:
                     with jax.named_scope("policy_round"):
                         (new_heads, pool_heads, pool_age, chosen, rej,
@@ -398,11 +406,12 @@ def _hetero_epoch_body(lr: float, plan: CohortPlan,
                         new_heads, pool_heads, pool_age, chosen, rej = out
                     else:
                         new_heads, pool_heads, pool_age, chosen = out
-                for k, co in enumerate(plan.cohorts):
-                    rows = jax.tree_util.tree_map(
-                        lambda g: g[members[k], :co.nf], new_heads)
-                    params_t[k] = {**params_t[k],
-                                   "heads": local_rows(rows, k)}
+                with jax.named_scope("union_view"):
+                    for k, co in enumerate(plan.cohorts):
+                        rows = jax.tree_util.tree_map(
+                            lambda g: g[members[k], :co.nf], new_heads)
+                        params_t[k] = {**params_t[k],
+                                       "heads": local_rows(rows, k)}
             else:
                 chosen = jnp.full((C, max_nf), -1, jnp.int32)
                 if admission is not None:
@@ -516,7 +525,7 @@ def _hetero_epoch_body(lr: float, plan: CohortPlan,
             out = out + (tele,)
         return out
 
-    return epoch
+    return hetero_epoch
 
 
 @functools.lru_cache(maxsize=None)
@@ -1034,6 +1043,10 @@ def _fit_cohorted(fed, n_epochs: int, cbs) -> None:
             if do_federate:
                 exchange_rounds += n_exch_epoch
                 pool_bytes += n_exch_epoch * exch_bytes
+                if rec is not None and n_exch_epoch:
+                    # the scored pool's rows and the real ones among them
+                    rec.count("union_rows", C * plan.max_nf * n_exch_epoch)
+                    rec.count("union_real_rows", sum(plan.nfs) * n_exch_epoch)
             for i in range(C):
                 histories[i].append(float(v_all[i]))
         fed.epoch += 1

@@ -38,7 +38,14 @@ Three pieces, all optional and all zero-cost when absent:
   inside), ``train_step`` and ``eval_best`` in their ops' ``op_name``
   metadata; under ``profile`` the recorder keeps each epoch program's
   instruction -> ``op_name`` map (``programs``), so a device profile's
-  bare op names can be put down to a layer.
+  bare op names can be put down to a layer.  The cohort engine's epoch
+  compiles as its own module, ``jit_hetero_epoch`` (the homogeneous one
+  is ``jit_epoch``), with one more scope, ``union_view``: the assembly
+  of the padded union pool and probes and the projection of the blended
+  heads back to native nf.  Its counters ``union_rows`` (C * max_nf) and
+  ``union_real_rows`` (the clients' summed nf), summed over exchange
+  rounds, give the real share of the scored pool; a homogeneous fit
+  sets neither.
   It serializes to JSONL, round-trips through checkpoint manifests
   (``to_json`` / ``from_json``) so resumed runs continue their trace, and
   ``tools/trace_export.py`` turns the event list into Chrome-trace /
@@ -219,6 +226,12 @@ METRICS: Dict[str, MetricSpec] = {m.name: m for m in [
     _m("restack_device_leaves", "counter", (int,), "leaves", "stacked-state "
        "leaves a batched fit stacked on the device (some row a device "
        "array)"),
+    _m("union_rows", "counter", (int,), "rows", "rows of the cohort "
+       "engine's padded union pool scored per exchange round (C * max_nf), "
+       "summed over exchange rounds"),
+    _m("union_real_rows", "counter", (int,), "rows", "real (unpadded) rows "
+       "among them (the sum of the clients' nf), summed over exchange "
+       "rounds"),
     _m("score_min", "histogram", _NUM, "", "per-round minimum Eq.-7 score "
        "over valid candidates"),
     _m("score_mean", "histogram", _NUM, "", "per-round mean Eq.-7 score "

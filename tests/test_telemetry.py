@@ -371,7 +371,8 @@ def test_epoch_scopes_in_op_metadata(monkeypatch, nf_choices, kernel):
                   telemetry=TEL.TelemetryPlan(rounds=False, profile=True))
     lowered = seen["fn"].lower(*seen["args"]).as_text(dialect="hlo",
                                                       debug_info=True)
-    compiled = fed._recorder.programs["jit_epoch"]
+    compiled = fed._recorder.programs[
+        "jit_epoch" if len(nf_choices) == 1 else "jit_hetero_epoch"]
     assert compiled == TEL.hlo_op_names(
         seen["fn"].lower(*seen["args"]).compile().as_text())
     for names in ([line.split('op_name="', 1)[1].split('"', 1)[0]
@@ -384,6 +385,35 @@ def test_epoch_scopes_in_op_metadata(monkeypatch, nf_choices, kernel):
     # each called function): the scorer runs inside the policy round
     assert all("policy_round" in p for p in paths if "eq7_score" in p)
     assert all(k.startswith("%") for k in compiled)
+
+
+@pytest.mark.parametrize("nf_choices", ((3,), (3, 4, 5)),
+                         ids=("batched", "cohort"))
+def test_union_view_scope_and_counters(nf_choices):
+    """The cohort engine's epoch compiles as its own module,
+    ``jit_hetero_epoch``, with ``union_view`` ops, and counts the padded
+    union's rows and the real ones per exchange round; the homogeneous
+    epoch stays ``jit_epoch`` with neither."""
+    fed, _ = _fit(_cfg(epochs=2), n=6, nf_choices=nf_choices,
+                  telemetry=TEL.TelemetryPlan(rounds=False, profile=True))
+    rec, stats = fed._recorder, fed.dispatch_stats
+    cohort = len(nf_choices) > 1
+    (module, ops), = rec.programs.items()
+    assert module == ("jit_hetero_epoch" if cohort else "jit_epoch")
+    union_ops = [o for o in ops.values() if "union_view" in o.split("/")]
+    counted = {k: rec.counters[k] for k in ("union_rows", "union_real_rows")
+               if k in rec.counters}
+    if not cohort:
+        assert not union_ops and counted == {}
+        return
+    assert union_ops
+    nfs = [c.nf for c in fed.clients]
+    assert sorted(set(nfs)) == [3, 4, 5]
+    rounds = stats["exchange_rounds"]
+    assert rounds == 2 * 2           # 2 epochs x 2 exchange sub-rounds
+    assert counted == {"union_rows": len(nfs) * max(nfs) * rounds,
+                       "union_real_rows": sum(nfs) * rounds}
+    assert {"union_rows", "union_real_rows"} <= set(TEL.schema())
 
 
 def test_hlo_op_names():
@@ -684,6 +714,35 @@ print("RESULT " + json.dumps(res))
 """
 
 
+_MESH_COHORT = r"""
+import json
+import jax
+assert jax.device_count() == 4, jax.devices()
+from repro.core.experiment import tensor_population
+from repro.core.federation import Federation, RoundSchedule
+from repro.core.hfl import HFLConfig
+from repro.core.mesh_federation import make_mesh
+from repro.core.telemetry import TelemetryPlan
+
+cfg = HFLConfig(epochs=1, R=10, mode="always", seed=3)
+clients = tensor_population(8, cfg, seed=1, nf_choices=(3, 4), n_train=20,
+                            n_eval=10).build(range(8))
+fed = Federation(clients, cfg, schedule=RoundSchedule(1, 10),
+                 engine="batched", mesh=make_mesh(),
+                 telemetry=TelemetryPlan(rounds=False, profile=True))
+fed.fit()
+res = {"modules": sorted(fed._recorder.programs),
+       "union_ops": sum("union_view" in o.split("/") for p in
+                        fed._recorder.programs.values() for o in p.values()),
+       "devices": fed.dispatch_stats["devices"],
+       "cohorts": fed.dispatch_stats["cohorts"],
+       "rounds": fed.dispatch_stats["exchange_rounds"],
+       "nfs": [c.nf for c in fed.clients],
+       "counters": fed._recorder.snapshot()}
+print("RESULT " + json.dumps(res))
+"""
+
+
 def _run_forced_devices(script: str, n_devices: int) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
@@ -712,3 +771,16 @@ def test_telemetry_on_forced_4_device_mesh():
     assert res["n_rounds"] == 2 * 2      # 2 epochs x 2 exchange rounds
     assert res["foreign_ok"] and res["scores_ok"]
     assert res["counter"] == 4 * 3 * 8
+
+
+def test_mesh_cohort_epoch_module_and_union_counters():
+    """The client-sharded cohort epoch compiles as ``jit_hetero_epoch``
+    too, carries the ``union_view`` scope and counts the union's rows."""
+    res = _run_forced_devices(_MESH_COHORT, 4)
+    assert res["devices"] == 4 and res["cohorts"] == 2
+    assert res["modules"] == ["jit_hetero_epoch"]
+    assert res["union_ops"] > 0
+    nfs, rounds = res["nfs"], res["rounds"]
+    assert rounds == 2
+    assert res["counters"]["union_rows"] == len(nfs) * max(nfs) * rounds
+    assert res["counters"]["union_real_rows"] == sum(nfs) * rounds
